@@ -43,7 +43,7 @@ _APP_REGISTRY: Dict[str, AppFunc] = {}
 
 
 def register_app(name: str, *, streaming: bool = False,
-                 finish: Optional[AppFunc] = None
+                 finish: Optional[AppFunc] = None, device: bool = False
                  ) -> Callable[[AppFunc], AppFunc]:
     """Register a pipeline component (paper §3.1).
 
@@ -53,12 +53,18 @@ def register_app(name: str, *, streaming: bool = False,
     The optional ``finish(ok_inputs, outputs, app)`` runs at batch
     resolution (all inputs terminal) to emit final outputs; without it
     the drop completes without writing.  Both engines honour the marks —
-    see ``docs/streaming.md``."""
+    see ``docs/streaming.md``.
+
+    ``device=True`` marks an app that drives the accelerator.  One process
+    owns the chip, so such an app runs on thread workers only; a process
+    worker fails its drop instead (``docs/multiprocess.md``)."""
     def deco(fn: AppFunc) -> AppFunc:
         if streaming:
             fn.streaming = True            # type: ignore[attr-defined]
         if finish is not None:
             fn.finish = finish             # type: ignore[attr-defined]
+        if device:
+            fn.device = True               # type: ignore[attr-defined]
         _APP_REGISTRY[name] = fn
         return fn
     return deco

@@ -107,8 +107,11 @@ class TrackingThreadPool(ThreadPoolExecutor):
         self._track_lock = threading.Lock()
 
     def submit(self, fn: Callable[..., Any], /, *args: Any, **kwargs: Any) -> Future:
-        fut = super().submit(fn, *args, **kwargs)
+        # tracked under the lock drain() snapshots with: the call may start
+        # (and be observed running) before submit returns, and drain must
+        # not miss it
         with self._track_lock:
+            fut = super().submit(fn, *args, **kwargs)
             self._tracked.add(fut)
         fut.add_done_callback(self._discard)
         return fut
@@ -380,10 +383,25 @@ def _run_spec(
         }
 
 
+def _pin_jax_to_host() -> None:
+    """Keep a worker's JAX, if any app imports it, on the host CPU.
+
+    The parent process owns the chip and holds the TPU library's lock; a
+    worker that loaded the library too would block on that lock or fail.
+    Apps that drive the chip are ``device`` apps, which the parent refuses
+    to send here (:meth:`ProcExecutor.run_batch`)."""
+    import sys
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:   # imported before the mailbox started
+        jax.config.update("jax_platforms", "cpu")
+
+
 def _worker_main(conn: Any, node: str, min_bytes: int) -> None:
     """Mailbox loop of one node worker.  Requests: ("run", bid, items,
     budget) / ("ping",) / ("stop",).  Replies: ("done", bid, results) /
     ("pong", pid)."""
+    _pin_jax_to_host()
     segments: Dict[str, SharedMemory] = {}
     try:
         while True:
@@ -545,6 +563,22 @@ class ProcExecutor:
             parent_fail: List[Dict[str, Any]] = []
             items: List[Tuple[int, bytes]] = []
             for spec in specs:
+                if getattr(spec.get("func"), "device", False):
+                    parent_fail.append(
+                        {
+                            "idx": int(spec["idx"]),
+                            "status": "err",
+                            "tb": (
+                                f"app {spec.get('uid', '')!r} is a device app: "
+                                "device apps run on thread workers, since the "
+                                "process that owns the chip runs them "
+                                f"(node {self.node} has a process worker)"
+                            ),
+                            "t0": now,
+                            "t1": now,
+                        }
+                    )
+                    continue
                 try:
                     items.append(
                         (int(spec["idx"]), pickle.dumps(self._encode_spec(spec), protocol=_PROTO))

@@ -2,7 +2,8 @@
 
 flash_attention: fused GQA attention (causal/window/softcap).
 ssd_scan: Mamba2 SSD chunk scan with VMEM-resident state.
-ops: jit'd wrappers (kernel on TPU, interpret-mode on CPU); ref: jnp oracles.
+ops: jit'd wrappers (compiled on TPU, interpret mode on the CPU backend);
+ref: jnp oracles.
 """
 from . import ops, ref
 from .flash_attention import flash_attention_bhsd

@@ -8,7 +8,8 @@ floats) is tuned to fit the ~16 MiB VMEM budget with D=128 head dims.
 
 Layout: (batch, heads, seq, head_dim).  GQA maps query head h to kv head
 h // (Hq // Hkv) in the kv index_map — no KV duplication in HBM.
-Validated against ``ref.mha_reference`` in interpret mode (CPU).
+Validated against ``ref.mha_reference`` in interpret mode on the CPU;
+``tests/test_tpu_compile.py`` compiles it for the chip at granite widths.
 """
 from __future__ import annotations
 
@@ -85,7 +86,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          logit_cap: float = 0.0,
                          block_q: int = DEFAULT_BLOCK_Q,
                          block_k: int = DEFAULT_BLOCK_K,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D) -> (B, Hq, Sq, D)."""
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]

@@ -1,11 +1,12 @@
 """jit'd dispatch wrappers: model-layout in, kernel-layout inside.
 
 ``flash_attention`` / ``ssd_scan`` are what the model layers call when
-``use_kernel=True``.  On CPU (this container) the Pallas body executes in
-interpret mode for validation; on TPU the same ``pallas_call`` lowers to
-Mosaic.  The jnp reference path (`repro.kernels.ref`) is the oracle and the
-default dry-run path (the dry-run measures the XLA program, and Mosaic
-kernels are opaque to HLO cost analysis anyway).
+``use_kernel=True``.  On the TPU the ``pallas_call`` lowers to Mosaic; on
+the CPU backend (the test suite) the same body runs in Pallas interpret mode;
+any other backend is refused rather than quietly interpreted.  The jnp
+reference path (`repro.kernels.ref`) is the oracle and the default dry-run
+path (the dry-run measures the XLA program, and Mosaic kernels are opaque
+to HLO cost analysis anyway).
 """
 from __future__ import annotations
 
@@ -19,8 +20,14 @@ from .flash_attention import flash_attention_bhsd
 from .ssd_scan import ssd_scan_bhsd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret on the CPU backend, compile on the TPU, refuse the rest."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for the TPU and interpret on the CPU; "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -33,7 +40,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vt = jnp.swapaxes(v, 1, 2)
     out = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
                                logit_cap=logit_cap, block_q=block_q,
-                               block_k=block_k, interpret=not _on_tpu())
+                               block_k=block_k, interpret=_interpret())
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -58,7 +65,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
             "kernel path starts from zero state; pass initial_state only "
             "on the jnp path")
     y, state = ssd_scan_bhsd(xt, dtt, a, bt, ct, chunk,
-                             interpret=not _on_tpu())
+                             interpret=_interpret())
     y = jnp.transpose(y, (0, 2, 1, 3))               # (B,S,H,P)
     # model layout state: (B,H,N,P)
     return y, state

@@ -35,7 +35,7 @@ from ..configs import (ARCH_NAMES, abstract_params, cell_supported,
                        get_config, input_specs)
 from ..models.common import SHAPES, ArchConfig, ShapeConfig
 from ..roofline import collective_bytes_from_hlo, model_flops, roofline_terms
-from ..sharding import batch_pspecs, cache_pspecs, param_pspecs, use_mesh
+from ..sharding import batch_pspecs, cache_pspecs, param_pspecs
 from ..sharding.rules import opt_pspecs
 from ..train.steps import (TrainState, make_decode_step, make_prefill_step,
                            make_train_step, train_state_init)
@@ -207,7 +207,7 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
                                    remat=variant.remat)
         bspecs = batch_pspecs(cfg, batch_abs, mesh,
                               batch_axes=variant.batch_axes)
-        with use_mesh(mesh), ctx, pctx:
+        with jax.set_mesh(mesh), ctx, pctx:
             lowered = jax.jit(
                 step,
                 in_shardings=(_spec_to_shardings(mesh, state_specs),
@@ -225,7 +225,7 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
         batch_abs = input_specs(cfg, shape)
         bspecs = batch_pspecs(cfg, batch_abs, mesh,
                               batch_axes=variant.batch_axes)
-        with use_mesh(mesh), ctx, pctx:
+        with jax.set_mesh(mesh), ctx, pctx:
             lowered = jax.jit(
                 step,
                 in_shardings=(_spec_to_shardings(mesh, pspecs),
@@ -243,7 +243,7 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
     cspecs = cache_pspecs(cfg, cache_abs, mesh)
     tok_spec = batch_pspecs(cfg, {"tokens": specs["tokens"]}, mesh,
                             batch_axes=variant.batch_axes)["tokens"]
-    with use_mesh(mesh), ctx, pctx:
+    with jax.set_mesh(mesh), ctx, pctx:
         lowered = jax.jit(
             step,
             in_shardings=(_spec_to_shardings(mesh, pspecs),

@@ -18,27 +18,89 @@ CLI:
   PYTHONPATH=src python -m repro.launch.serve --sessions 8 --concurrent 4
   PYTHONPATH=src python -m repro.launch.serve --sessions 8 \
       --stats-json results/serve_stats.json   # registry snapshot dump
+  PYTHONPATH=src python -m repro.launch.serve --arch granite_moe_3b_a800m \
+      --full --execution compiled --prompt 512 --decode 32   # published width
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
+import functools
 import json
 import time
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..configs import get_smoke_config
+from ..configs import ARCH_NAMES, get_config, get_smoke_config
 from ..core import (EngineConfig, EngineManager, Pipeline, TelemetryConfig,
                     register_app)
 from ..dsl import GraphBuilder
 from ..models import model as M
 from ..models.common import ArchConfig
 from ..train import make_decode_step, make_prefill_step
+from .compile_cache import setup_compile_cache
+
+
+@functools.lru_cache(maxsize=None)
+def serving_steps(cfg: ArchConfig) -> Tuple[Callable, Callable]:
+    """The jitted prefill and greedy decode steps of ``cfg``: one pair per
+    config, shared by the served apps and :func:`reference_tokens`, so both
+    run the same compiled programs."""
+    return jax.jit(make_prefill_step(cfg)), jax.jit(make_decode_step(cfg))
+
+
+def make_prompts(cfg: ArchConfig, num_requests: int,
+                 prompt_len: int) -> np.ndarray:
+    """The fixed prompt batch every serving run uses (seeded)."""
+    rng = np.random.default_rng(0)
+    return rng.integers(0, cfg.vocab_size,
+                        size=(num_requests, prompt_len)).astype(np.int32)
+
+
+def prefill_microbatch(cfg: ArchConfig, params: Any, chunk: np.ndarray,
+                       max_seq: int) -> Tuple[jax.Array, Dict[str, Any]]:
+    """Prefill one microbatch of prompts; returns the first generated
+    token per row, (mb, 1), and the cache grown to ``max_seq``."""
+    prefill_step, _ = serving_steps(cfg)
+    mb, prompt_len = chunk.shape
+    batch = {"tokens": jnp.asarray(chunk)}
+    if cfg.family == "encdec":
+        batch["frames"] = jnp.zeros(
+            (mb, max(prompt_len // cfg.encoder_ratio, 1), cfg.d_model),
+            jnp.float32)
+    next_tok, cache = prefill_step(params, batch)
+    # grow cache to max_seq for the decode phase
+    grown = M.init_cache(cfg, mb, max_seq)
+
+    def fill(dst, src):
+        pad = [(0, d - s) for d, s in zip(dst.shape, src.shape)]
+        return jnp.pad(src, pad).astype(dst.dtype)
+    return next_tok[:, None], jax.tree.map(fill, grown, cache)
+
+
+def reference_tokens(cfg: ArchConfig, params: Any, prompts: np.ndarray, *,
+                     microbatch: int, decode_steps: int) -> np.ndarray:
+    """The plain reference of the serving graph: the same prefill and
+    decode steps run straight, one microbatch after another, with no
+    engine.  Returns the (num_requests, decode_steps) greedy tokens the
+    served ``responses`` must equal."""
+    _, decode_one = serving_steps(cfg)
+    prompt_len = prompts.shape[1]
+    rows = []
+    for start in range(0, len(prompts), microbatch):
+        tok, cache = prefill_microbatch(
+            cfg, params, prompts[start:start + microbatch],
+            prompt_len + decode_steps)
+        toks = [tok]
+        for i in range(decode_steps - 1):
+            tok, cache = decode_one(params, cache, tok,
+                                    jnp.int32(prompt_len + i))
+            toks.append(tok)
+        rows.append(np.asarray(jnp.concatenate(toks, axis=1)))
+    return np.concatenate(rows, axis=0)
 
 
 def _dump_stats(path: str, payload: Dict[str, Any]) -> None:
@@ -57,7 +119,7 @@ def run_serving(cfg: ArchConfig, *, num_requests: int = 8,
                 sessions: int = 1, max_concurrent: int = 4,
                 stats_json: Optional[str] = None,
                 streaming: bool = False, execution: str = "objects",
-                hooks: Any = None) -> Dict[str, Any]:
+                hooks: Any = None, params: Any = None) -> Dict[str, Any]:
     """Serve ``num_requests`` prompts through the graph engine.
 
     ``streaming=True`` switches token delivery to the chunk lane: each
@@ -65,41 +127,30 @@ def run_serving(cfg: ArchConfig, *, num_requests: int = 8,
     ``gen`` drop, whose edge into the assembler is streaming — the
     assembler accumulates chunks as they arrive (on either engine) and
     concatenates at batch resolution.  ``hooks`` (ExecHooks) forwards to
-    :meth:`Pipeline.execute` for chunk/wave observability.
+    :meth:`Pipeline.execute` for chunk/wave observability.  ``params``
+    defaults to ``init_params(cfg, PRNGKey(0))``; pass them to serve
+    several runs from one copy.  The result's ``responses`` are the
+    (num_requests, decode_steps) tokens of the (last) session.
     """
     assert num_requests % microbatch == 0
     n_micro = num_requests // microbatch
     max_seq = prompt_len + decode_steps
 
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    prefill_step = jax.jit(make_prefill_step(cfg))
-    decode_one = jax.jit(make_decode_step(cfg))
+    if params is None:
+        params = M.init_params(cfg, jax.random.PRNGKey(0))
+    _, decode_one = serving_steps(cfg)
+    prompts = make_prompts(cfg, num_requests, prompt_len)
 
-    rng = np.random.default_rng(0)
-    prompts = rng.integers(0, cfg.vocab_size,
-                           size=(num_requests, prompt_len)).astype(np.int32)
-
-    @register_app("serve/prefill")
+    @register_app("serve/prefill", device=True)
     def prefill_app(inputs, outputs, app):
         (mb,) = app.meta["oid"]
-        chunk = jnp.asarray(prompts[mb * microbatch:(mb + 1) * microbatch])
-        batch = {"tokens": chunk}
-        if cfg.family == "encdec":
-            batch["frames"] = jnp.zeros(
-                (microbatch, max(prompt_len // cfg.encoder_ratio, 1),
-                 cfg.d_model), jnp.float32)
-        next_tok, cache = prefill_step(params, batch)
-        # grow cache to max_seq for the decode phase
-        grown = M.init_cache(cfg, microbatch, max_seq)
-
-        def fill(dst, src):
-            pad = [(0, d - s) for d, s in zip(dst.shape, src.shape)]
-            return jnp.pad(src, pad).astype(dst.dtype)
-        cache = jax.tree.map(fill, grown, cache)
+        next_tok, cache = prefill_microbatch(
+            cfg, params, prompts[mb * microbatch:(mb + 1) * microbatch],
+            max_seq)
         for o in outputs:
-            o.write({"next": next_tok[:, None], "cache": cache})
+            o.write({"next": next_tok, "cache": cache})
 
-    @register_app("serve/decode")
+    @register_app("serve/decode", device=True)
     def decode_app(inputs, outputs, app):
         st = inputs[0].read()
         tok, cache = st["next"], st["cache"]
@@ -111,7 +162,7 @@ def run_serving(cfg: ArchConfig, *, num_requests: int = 8,
         for o in outputs:
             o.write(np.asarray(jnp.concatenate(toks, axis=1)))
 
-    @register_app("serve/decode-stream")
+    @register_app("serve/decode-stream", device=True)
     def decode_stream_app(inputs, outputs, app):
         # streaming variant: one chunk per generated token position so
         # the assembler overlaps with generation; chunks are tagged with
@@ -194,12 +245,14 @@ def run_serving(cfg: ArchConfig, *, num_requests: int = 8,
             })
     gen_tokens = num_requests * decode_steps
     result = {
+        "responses": out,
         "responses_shape": tuple(out.shape),
         "wall_s": wall,
         "gen_tokens_per_s": gen_tokens / wall,
         "drops": sum(rep.status_counts.values()),
     }
-    print(f"[serve] {num_requests} requests x {decode_steps} tokens in "
+    print(f"[serve] {cfg.name}: {num_requests} requests x {decode_steps} "
+          f"tokens in "
           f"{wall:.2f}s ({result['gen_tokens_per_s']:.1f} tok/s), "
           f"responses {out.shape}")
     return result
@@ -232,6 +285,7 @@ def _run_sessions(lg, *, sessions: int, num_nodes: int,
             _dump_stats(stats_json, stats)
     gen_tokens = sessions * num_requests * decode_steps
     result = {
+        "responses": out,
         "responses_shape": tuple(out.shape),
         "sessions": sessions,
         "wall_s": wall,
@@ -274,8 +328,14 @@ def main() -> None:
                     default="objects",
                     help="execution substrate for the single-session "
                          "path (--sessions 1)")
+    ap.add_argument("--arch", choices=ARCH_NAMES, default="codeqwen15_7b",
+                    help="registry config to serve")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published config instead of its "
+                         "smoke-size variant (needs the chip)")
     args = ap.parse_args()
-    cfg = get_smoke_config("codeqwen15_7b")
+    setup_compile_cache()
+    cfg = (get_config if args.full else get_smoke_config)(args.arch)
     run_serving(cfg, num_requests=args.requests,
                 microbatch=args.microbatch, prompt_len=args.prompt,
                 decode_steps=args.decode, sessions=args.sessions,

@@ -36,6 +36,7 @@ from ..data import synthetic_batch
 from ..dsl import GraphBuilder
 from ..models.common import ArchConfig
 from ..train import make_train_step, train_state_init
+from .compile_cache import setup_compile_cache
 
 PRESETS: Dict[str, ArchConfig] = {
     # ~100M-class decoder (TPU-sized example; minutes/step on 1 CPU).
@@ -117,7 +118,7 @@ def run_training(cfg: ArchConfig, *, steps: int = 40, shards: int = 2,
         for o in outputs:
             o.write(b)
 
-    @register_app("train/step")
+    @register_app("train/step", device=True)
     def step_app(inputs, outputs, app):
         state = None
         shards_np = []
@@ -204,6 +205,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--lr", type=float, default=1e-3)
     args = ap.parse_args()
+    setup_compile_cache()
     cfg = PRESETS[args.preset]
     run_training(cfg, steps=args.steps, shards=args.shards,
                  batch_per_shard=args.batch_per_shard, seq=args.seq,

@@ -243,8 +243,8 @@ class KeyGen:
     """Deterministic key splitter for param init."""
 
     def __init__(self, key: jax.Array) -> None:
-        self._key = key
+        self.key = key
 
     def __call__(self) -> jax.Array:
-        self._key, sub = jax.random.split(self._key)
+        self.key, sub = jax.random.split(self.key)
         return sub

@@ -112,8 +112,42 @@ def _init_dense_block(kg: KeyGen, cfg: ArchConfig, dt,
     return p
 
 
-def _stack(layers):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _init_layer(cfg: ArchConfig, kind: str, key: jax.Array):
+    """One layer's params of ``kind`` ("dense" | "cross" | "ssm") from
+    ``key``; returns them with the key advanced past their draws."""
+    kg, dt = KeyGen(key), _dtype(cfg)
+    if kind == "ssm":
+        p = {"norm": jnp.zeros((cfg.d_model,), dt),
+             "mamba": init_mamba2(kg, cfg, dt)}
+    else:
+        p = _init_dense_block(kg, cfg, dt, cross=kind == "cross")
+    return p, kg.key
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_layer(stack, layer, i):
+    return jax.tree.map(
+        lambda s, x: jax.lax.dynamic_update_index_in_dim(s, x, i, 0),
+        stack, layer)
+
+
+def _init_stacked(kg: KeyGen, cfg: ArchConfig, kind: str,
+                  n: int) -> Dict[str, Any]:
+    """``n`` layers' params stacked on a leading axis, drawn from ``kg`` in
+    order — the same keys as ``n`` successive eager layer inits.
+
+    One jitted call initialises one layer and writes it into a preallocated
+    (donated) stack, so the compile covers one layer whatever the depth and
+    the peak is the stack plus one layer — a full-width model built eagerly
+    and then stacked would need both copies at once."""
+    shapes, _ = jax.eval_shape(
+        functools.partial(_init_layer, cfg, kind), kg.key)
+    stack = jax.tree.map(lambda a: jnp.zeros((n, *a.shape), a.dtype), shapes)
+    for i in range(n):
+        layer, kg.key = _init_layer(cfg, kind, kg.key)
+        stack = _put_layer(stack, layer, i)
+    return stack
 
 
 # ---------------------------------------------------------------------------
@@ -133,27 +167,19 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> Dict[str, Any]:
         params["lm_head"] = dense_init(kg(), (d, vp), dt, fan_in=d)
 
     if cfg.family in ("dense", "moe", "vlm"):
-        params["layers"] = _stack(
-            [_init_dense_block(kg, cfg, dt) for _ in range(cfg.num_layers)])
+        params["layers"] = _init_stacked(kg, cfg, "dense", cfg.num_layers)
     elif cfg.family == "ssm":
-        params["layers"] = _stack(
-            [{"norm": jnp.zeros((d,), dt), "mamba": init_mamba2(kg, cfg, dt)}
-             for _ in range(cfg.num_layers)])
+        params["layers"] = _init_stacked(kg, cfg, "ssm", cfg.num_layers)
     elif cfg.family == "hybrid":
         assert cfg.shared_attn_period > 0
         assert cfg.num_layers % cfg.shared_attn_period == 0
-        params["layers"] = _stack(
-            [{"norm": jnp.zeros((d,), dt), "mamba": init_mamba2(kg, cfg, dt)}
-             for _ in range(cfg.num_layers)])
+        params["layers"] = _init_stacked(kg, cfg, "ssm", cfg.num_layers)
         params["shared"] = _init_dense_block(kg, cfg, dt)
     elif cfg.family == "encdec":
-        params["enc_layers"] = _stack(
-            [_init_dense_block(kg, cfg, dt)
-             for _ in range(cfg.num_encoder_layers)])
+        params["enc_layers"] = _init_stacked(kg, cfg, "dense",
+                                             cfg.num_encoder_layers)
         params["enc_norm"] = jnp.zeros((d,), dt)
-        params["layers"] = _stack(
-            [_init_dense_block(kg, cfg, dt, cross=True)
-             for _ in range(cfg.num_layers)])
+        params["layers"] = _init_stacked(kg, cfg, "cross", cfg.num_layers)
     else:
         raise ValueError(f"unknown family {cfg.family!r}")
     return params

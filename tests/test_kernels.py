@@ -1,4 +1,6 @@
-"""Per-kernel shape/dtype sweeps against the jnp oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps against the jnp oracles (interpret mode).
+
+The chip compiles of the same kernels live in tests/test_tpu_compile.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,7 +29,7 @@ class TestFlashAttention:
         k = rnd(1, (b, hkv, sk, d), jnp.float32)
         v = rnd(2, (b, hkv, sk, d), jnp.float32)
         out = flash_attention_bhsd(q, k, v, causal=False,
-                                   block_q=32, block_k=32)
+                                   block_q=32, block_k=32, interpret=True)
         want = ref.mha_reference(q, k, v, causal=False)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
@@ -41,7 +43,8 @@ class TestFlashAttention:
         k = rnd(4, (2, 2, 64, 32), jnp.float32)
         v = rnd(5, (2, 2, 64, 32), jnp.float32)
         out = flash_attention_bhsd(q, k, v, causal=causal, window=window,
-                                   logit_cap=cap, block_q=32, block_k=32)
+                                   logit_cap=cap, block_q=32, block_k=32,
+                                   interpret=True)
         want = ref.mha_reference(q, k, v, causal=causal, window=window,
                                  logit_cap=cap)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -54,7 +57,8 @@ class TestFlashAttention:
         q = rnd(6, (1, 2, 64, 32), dtype, 0.5)
         k = rnd(7, (1, 2, 64, 32), dtype, 0.5)
         v = rnd(8, (1, 2, 64, 32), dtype, 0.5)
-        out = flash_attention_bhsd(q, k, v, block_q=32, block_k=32)
+        out = flash_attention_bhsd(q, k, v, block_q=32, block_k=32,
+                                   interpret=True)
         want = ref.mha_reference(q.astype(jnp.float32),
                                  k.astype(jnp.float32),
                                  v.astype(jnp.float32))
@@ -66,8 +70,10 @@ class TestFlashAttention:
         q = rnd(9, (1, 2, 128, 32), jnp.float32)
         k = rnd(10, (1, 2, 128, 32), jnp.float32)
         v = rnd(11, (1, 2, 128, 32), jnp.float32)
-        o1 = flash_attention_bhsd(q, k, v, block_q=32, block_k=32)
-        o2 = flash_attention_bhsd(q, k, v, block_q=64, block_k=128)
+        o1 = flash_attention_bhsd(q, k, v, block_q=32, block_k=32,
+                                  interpret=True)
+        o2 = flash_attention_bhsd(q, k, v, block_q=64, block_k=128,
+                                  interpret=True)
         np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                    atol=2e-5, rtol=2e-5)
 
@@ -76,7 +82,7 @@ class TestFlashAttention:
         k = rnd(13, (1, 1, 64, 16), jnp.float32)
         v = rnd(14, (1, 1, 64, 16), jnp.float32)
         f = jax.jit(lambda a, b, c: flash_attention_bhsd(
-            a, b, c, block_q=32, block_k=32))
+            a, b, c, block_q=32, block_k=32, interpret=True))
         np.testing.assert_allclose(
             np.asarray(f(q, k, v)),
             np.asarray(ref.mha_reference(q, k, v)), atol=2e-5, rtol=2e-5)
@@ -95,7 +101,7 @@ class TestSSDScan:
         a = -jnp.exp(rnd(2, (h,), jnp.float32, 0.3))
         bb = rnd(3, (b, h, s, n), jnp.float32, 0.5)
         cc = rnd(4, (b, h, s, n), jnp.float32, 0.5)
-        y, st = ssd_scan_bhsd(x, dt, a, bb, cc, chunk)
+        y, st = ssd_scan_bhsd(x, dt, a, bb, cc, chunk, interpret=True)
         yr, str_ = ref.ssd_reference(x, dt, a, bb, cc)
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                    atol=2e-4, rtol=2e-4)
@@ -108,8 +114,8 @@ class TestSSDScan:
         a = -jnp.exp(rnd(7, (2,), jnp.float32, 0.3))
         bb = rnd(8, (1, 2, 64, 4), jnp.float32, 0.5)
         cc = rnd(9, (1, 2, 64, 4), jnp.float32, 0.5)
-        y1, s1 = ssd_scan_bhsd(x, dt, a, bb, cc, 8)
-        y2, s2 = ssd_scan_bhsd(x, dt, a, bb, cc, 32)
+        y1, s1 = ssd_scan_bhsd(x, dt, a, bb, cc, 8, interpret=True)
+        y2, s2 = ssd_scan_bhsd(x, dt, a, bb, cc, 32, interpret=True)
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                    atol=2e-4, rtol=2e-4)
         np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
@@ -121,7 +127,7 @@ class TestSSDScan:
         a = -jnp.exp(rnd(12, (2,), jnp.float32, 0.3))
         bb = rnd(13, (1, 2, 32, 4), jnp.bfloat16, 0.5)
         cc = rnd(14, (1, 2, 32, 4), jnp.bfloat16, 0.5)
-        y, _ = ssd_scan_bhsd(x, dt, a, bb, cc, 8)
+        y, _ = ssd_scan_bhsd(x, dt, a, bb, cc, 8, interpret=True)
         yr, _ = ref.ssd_reference(x.astype(jnp.float32), dt, a,
                                   bb.astype(jnp.float32),
                                   cc.astype(jnp.float32))
@@ -148,7 +154,8 @@ class TestModelScanAgreement:
         bt = jnp.repeat(jnp.transpose(bb, (0, 2, 1, 3)), h, axis=1)
         ct = jnp.repeat(jnp.transpose(cc, (0, 2, 1, 3)), h, axis=1)
         y_ref, st_ref = ref.ssd_reference(xt, dtt, a, bt, ct)
-        y_kern, st_kern = ssd_scan_bhsd(xt, dtt, a, bt, ct, 16)
+        y_kern, st_kern = ssd_scan_bhsd(xt, dtt, a, bt, ct, 16,
+                                        interpret=True)
         y_model_t = jnp.transpose(y_model, (0, 2, 1, 3))
         np.testing.assert_allclose(np.asarray(y_model_t),
                                    np.asarray(y_ref), atol=2e-4, rtol=2e-4)

@@ -333,6 +333,75 @@ class TestProcessEngineEquivalence:
             assert s.state_of("good") is DropState.COMPLETED
             assert s.read("gout") == 4
 
+    def test_device_app_fails_its_drop_on_process_workers(self):
+        g = GraphBuilder("pp_device")
+        g.data("src")
+        g.component("good", app="pp/double", time=1.0)
+        g.data("gout")
+        g.chain("src", "good", "gout")
+        g.component("dev", app="pp/device", time=1.0)
+        g.data("dout")
+        g.chain("src", "dev", "dout")
+        with Pipeline(num_nodes=2, algorithm="none", execution="compiled",
+                      workers="process") as p:
+            rep = p.run(g.graph(), inputs={"src": 2})
+            assert not rep.ok
+            s = p.session
+            assert s.state_of("dev") is DropState.ERROR
+            assert "device apps run on thread workers" in s.error_info.get(
+                s.index_of("dev"), "")
+            assert s.state_of("good") is DropState.COMPLETED
+            assert s.read("gout") == 4
+
+
+# ---------------------------------------------------------------------------
+# one process per chip: workers never load the TPU library
+# ---------------------------------------------------------------------------
+
+
+@register_app("pp/device", device=True)
+def pp_device(inputs, outputs, app):   # pragma: no cover - refused
+    raise AssertionError("a device app ran in a process worker")
+
+
+@register_app("pp/jax-platforms")
+def pp_jax_platforms(inputs, outputs, app):
+    import jax
+    for o in outputs:
+        o.write((os.environ.get("JAX_PLATFORMS"), jax.config.jax_platforms))
+
+
+class TestWorkerStaysOffTheChip:
+    def test_worker_jax_is_pinned_to_cpu(self, monkeypatch):
+        # the worker inherits the parent's environment at spawn; whatever
+        # platform the parent asked for, the worker's JAX stays on the CPU
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        plane = PayloadPlane()
+        ex = ProcExecutor("nodeT", plane)
+        try:
+            spec = {"idx": 0, "uid": "j", "func": pp_jax_platforms,
+                    "meta": {}, "inputs": [], "outputs": [(1, "out", {})]}
+            (res,) = ex.run_batch([spec], budget=60.0)
+            assert res["status"] == "ok", res.get("tb")
+            assert res["writes"] == [(1, ("cpu", "cpu"))]
+        finally:
+            ex.shutdown()
+            plane.close()
+
+    def test_device_app_refused_before_dispatch(self):
+        plane = PayloadPlane()
+        ex = ProcExecutor("nodeT", plane)
+        try:
+            spec = {"idx": 0, "uid": "dev", "func": pp_device, "meta": {},
+                    "inputs": [], "outputs": [(1, "out", {})]}
+            (res,) = ex.run_batch([spec], budget=30.0)
+            assert res["status"] == "err"
+            assert "'dev' is a device app" in res["tb"]
+            assert "device apps run on thread workers" in res["tb"]
+        finally:
+            ex.shutdown()
+            plane.close()
+
 
 # ---------------------------------------------------------------------------
 # satellite: MemoryPayload.nbytes must not serialise buffer values

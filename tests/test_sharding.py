@@ -75,7 +75,6 @@ class TestParamRules:
         cfg = dataclasses.replace(get_smoke_config("codeqwen15_7b"),
                                   num_heads=4, num_kv_heads=4, d_ff=64,
                                   vocab_size=256)
-        from repro.sharding.compat import use_mesh
         step = make_train_step(cfg, num_microbatches=2, remat=True)
         state = jax.eval_shape(
             lambda: train_state_init(cfg, jax.random.PRNGKey(0)))
@@ -87,7 +86,7 @@ class TestParamRules:
         tos = lambda t: jax.tree.map(
             lambda s: NamedSharding(mesh, s), t,
             is_leaf=lambda x: isinstance(x, P))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=(tos(sspecs), tos(bspecs)),
                               donate_argnums=(0,)).lower(state, batch)
         compiled = lowered.compile()

@@ -1,0 +1,76 @@
+"""Chip compiles of the Pallas kernels at the widths the models run them at.
+
+Each test lowers a kernel with ``interpret=False`` for one chip of a
+described (not attached) TPU v5e topology and checks that Mosaic accepted it:
+the compiled program carries a ``tpu_custom_call``.  Nothing runs; this is
+what the chip's compiler would refuse, caught without the chip.
+
+The topology is described inside a module fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.  The persistent compilation cache is off around these compiles,
+since an entry compiled for a described chip cannot be read back here.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import flash_attention_bhsd, ssd_scan_bhsd
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_flash_attention_compiles_at_granite_widths(one_chip):
+    cfg = get_config("granite_moe_3b_a800m")
+    b, s, d = 1, 2048, cfg.resolved_head_dim
+    q = _spec((b, cfg.num_heads, s, d), jnp.bfloat16, one_chip)
+    kv = _spec((b, cfg.num_kv_heads, s, d), jnp.bfloat16, one_chip)
+    compiled = jax.jit(lambda q, k, v: flash_attention_bhsd(
+        q, k, v, causal=True, interpret=False)).lower(q, kv, kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
+    cfg = get_config("mamba2_1_3b")
+    b, s = 1, 2048
+    h, p, n = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
+    x = _spec((b, h, s, p), jnp.bfloat16, one_chip)
+    dt = _spec((b, h, s), jnp.float32, one_chip)
+    a = _spec((h,), jnp.float32, one_chip)
+    bc = _spec((b, h, s, n), jnp.bfloat16, one_chip)
+    compiled = jax.jit(lambda x, dt, a, b, c: ssd_scan_bhsd(
+        x, dt, a, b, c, cfg.ssm_chunk, interpret=False)).lower(
+            x, dt, a, bc, bc).compile()
+    assert "tpu_custom_call" in compiled.as_text()
