@@ -398,6 +398,8 @@ class _Dispatch:
         otherwise, with node executors available, per-node batches run
         concurrently on the node thread pools — the object engine's wave
         parallelism, which the plain sequential loop used to serialise."""
+        if self.tl is not None and ids.size:
+            self.tl.stamp_ready(ids, time.monotonic())
         if ids.size and self.hooks is not None \
                 and self.hooks.python_runner is not None:
             self.hooks.python_runner(self, ids)
@@ -993,7 +995,25 @@ def execute_frontier(session: CompiledSession,
     Returns True when every drop reached a terminal state within
     ``timeout``; on timeout the session is left RUNNING and False is
     returned (the engine reports state "TIMEOUT").
+
+    With a session ``Timeline``, each call adds one execute span to it,
+    opened and closed by an ``engine.anchor`` on a running profiler trace.
     """
+    tl = session.timeline
+    if tl is None:
+        return _execute_waves(session, timeout, hooks, executors, stream)
+    t0 = tl.begin_execute()
+    try:
+        return _execute_waves(session, timeout, hooks, executors, stream)
+    finally:
+        tl.end_execute(t0)
+
+
+def _execute_waves(session: CompiledSession, timeout: float,
+                   hooks: Optional[ExecHooks],
+                   executors: Optional[Dict[str, Any]],
+                   stream: Union[StreamConfig, bool, None]) -> bool:
+    """The body of :func:`execute_frontier`: the waves of one call."""
     pgt = session.pgt
     n = pgt.num_drops
     session.start()

@@ -53,22 +53,23 @@ class AdmissionError(RuntimeError):
 class SessionTicket:
     """Handle for one submitted session: its future report + timings.
 
-    ``latency`` is the *session* latency a client observes — submit to
-    report, queueing included — which is what bench_serve's p50/p99
-    quantiles are computed over.
+    ``latency`` is the *session* latency a client observes — from the
+    call to ``submit`` to the report, admission, template lookup,
+    materialisation and queueing included — which is what bench_serve's
+    p50/p99 quantiles are computed over.  ``queue_delay`` is the call to
+    the start of execution.
     """
 
     __slots__ = ("session_id", "template_key", "session", "future",
                  "submitted_at", "started_at", "finished_at", "_accounted")
 
     def __init__(self, session_id: str, template_key: str,
-                 session: CompiledSession, future: "Future[ExecutionReport]"
-                 ) -> None:
+                 session: CompiledSession, submitted_at: float) -> None:
         self.session_id = session_id
         self.template_key = template_key
         self.session = session
-        self.future = future
-        self.submitted_at = time.monotonic()
+        self.future: "Optional[Future[ExecutionReport]]" = None
+        self.submitted_at = submitted_at
         self.started_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self._accounted = threading.Event()   # manager _on_done ran
@@ -206,6 +207,7 @@ class EngineManager:
         ``max_concurrent + max_pending`` slots are taken.  With
         ``block=True`` waits (up to ``admission_timeout``) for a slot.
         """
+        submitted_at = time.monotonic()
         if self._closed:
             raise RuntimeError("EngineManager is closed")
         acquired = (self._slots.acquire(timeout=admission_timeout)
@@ -232,12 +234,13 @@ class EngineManager:
             if inputs:
                 for uid, value in inputs.items():
                     session.write(uid, value)
-            future = self._pool.submit(
-                self._run, session, template, timeout)
+            ticket = SessionTicket(session_id, template.key, session,
+                                   submitted_at)
+            future = ticket.future = self._pool.submit(
+                self._run, ticket, timeout)
         except BaseException:
             self._slots.release()
             raise
-        ticket = SessionTicket(session_id, template.key, session, future)
         with self._lock:
             self._tickets[session_id] = ticket
             self.stats_counters["submitted"] += 1
@@ -267,15 +270,12 @@ class EngineManager:
         future.add_done_callback(_on_done)
         return ticket
 
-    def _run(self, session: CompiledSession, template: GraphTemplate,
-             timeout: float) -> ExecutionReport:
+    def _run(self, ticket: SessionTicket, timeout: float) -> ExecutionReport:
         """Execute one admitted session; never lets an exception escape
         into the pool — errors become a failed report (isolation)."""
         from .exec_compiled import execute_frontier
-        ticket = self._tickets.get(session.session_id)
-        if ticket is not None:
-            ticket.started_at = time.monotonic()
-        t0 = time.monotonic()
+        session = ticket.session
+        ticket.started_at = t0 = time.monotonic()
         try:
             finished = execute_frontier(session, timeout=timeout,
                                         executors=self.executors)

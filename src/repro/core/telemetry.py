@@ -16,7 +16,12 @@ Three layers, all off by default and enabled via :class:`TelemetryConfig`:
   ``CompiledSession``.  Batch fast paths (noop/identity/sleep and data
   drops) stamp whole waves vectorized; real Python apps are stamped
   individually around the registry call, so speculation and retries show
-  their true durations.
+  their true durations.  Registry apps also get ``t_ready`` (when their
+  wave handed them to dispatch) and, at read time, the app that
+  activated them (:meth:`Timeline.causes`); each ``execute_frontier``
+  call adds one execute span.  The call's entry and exit and each app's
+  stamp put an ``engine.anchor`` on a running ``jax.profiler`` trace, so
+  :func:`fit_clock` can map the stamps onto the trace's clock.
 * :class:`MetricsRegistry` — process-local counters/gauges/fixed-bucket
   histograms (no external deps), wired into ``execute_frontier`` (waves,
   frontier sizes, dispatch batches), ``EngineManager`` (admission,
@@ -35,17 +40,21 @@ vs clean drops/s and ``scripts/check_bench.py`` enforces the committed
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Any, Dict, List, Optional, Sequence, Union
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from .pgt import KIND_DATA, csr_gather_with_counts
+
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span",
-    "TelemetryConfig", "Timeline", "export_chrome_trace",
+    "ANCHOR", "ClockFit", "Counter", "Gauge", "Histogram",
+    "MetricsRegistry", "Span", "TelemetryConfig", "Timeline",
+    "emit_anchor", "export_chrome_trace", "fit_clock", "read_anchors",
     "FRONTIER_BUCKETS", "LATENCY_BUCKETS_S",
 ]
 
@@ -137,20 +146,42 @@ class Timeline:
     order does not matter; batch stamps come from the single scheduler
     thread, and only the allocation itself is locked (scalar stamps
     race in from pool workers).
+
+    Where the time between apps goes:
+
+    * ``t_ready`` — when the wave handed a registry app to dispatch
+      (``_Dispatch._run_python_batch``), stamped like ``stamp_batch``:
+      one deferred ``(ids, t)`` per wave, its own array allocated on
+      first read.  ``t_start - t_ready`` is the time a runnable app
+      waited for a worker (behind its node's batch or a full pool);
+    * :meth:`causes` — the producer app whose ``t_end`` completed an
+      app's last input, from the in-CSR at read time;
+      ``t_ready - t_end(cause)`` is the time the app waited at the wave
+      barrier after its own inputs were done;
+    * ``exec_spans`` — ``(t0, t1)`` of each ``execute_frontier`` call
+      (a resume adds one), bracketed by ``engine.anchor`` instants on a
+      running ``jax.profiler`` trace (:func:`emit_anchor`); each scalar
+      ``stamp`` adds one more, so a session's anchors cover its apps.
     """
 
-    __slots__ = ("pgt", "_t_start", "_t_end", "_wave", "_node", "epoch",
-                 "max_wave", "_pending", "_alloc_lock", "chunks")
+    __slots__ = ("pgt", "session_id", "_t_start", "_t_end", "_wave",
+                 "_node", "_t_ready", "epoch", "max_wave", "_pending",
+                 "_pending_ready", "_alloc_lock", "chunks", "exec_spans")
 
     def __init__(self, session: Any) -> None:
         self.pgt = session.pgt
+        self.session_id = session.session_id
         self._t_start: Optional[np.ndarray] = None
         self._t_end: Optional[np.ndarray] = None
         self._wave: Optional[np.ndarray] = None
         self._node: Optional[np.ndarray] = None
+        self._t_ready: Optional[np.ndarray] = None
         self.epoch = time.monotonic()     # export timebase reference
         self.max_wave = -1                # resume continues from here
         self._pending: List[tuple] = []   # deferred batch stamps
+        self._pending_ready: List[tuple] = []   # deferred (ids, t_ready)
+        # (t0, t1) per execute_frontier call; t1 is NaN while it runs
+        self.exec_spans: List[Tuple[float, float]] = []
         self._alloc_lock = threading.Lock()
         # streaming chunk spans: (consumer idx, seq, t0, t1) per chunk
         # processed by the compiled lane.  A plain list — chunks are
@@ -216,7 +247,10 @@ class Timeline:
 
     def stamp(self, i: int, t0: float, t1: float, wave: int,
               node: Optional[int] = None) -> None:
-        """Immediate scalar stamp for one registry-app execution."""
+        """Immediate scalar stamp for one registry-app execution (and an
+        ``engine.anchor``, so that the anchors of a trace span its apps,
+        not only the edges of the execute calls)."""
+        emit_anchor(session=self.session_id, at="app")
         self._ensure()
         self._t_start[i] = t0
         self._t_end[i] = t1
@@ -225,6 +259,73 @@ class Timeline:
             self._node[i] = node
         if wave > self.max_wave:
             self.max_wave = wave
+
+    def stamp_ready(self, ids: np.ndarray, t: float) -> None:
+        """Deferred ready stamp for one wave's registry apps (O(1); the
+        caller must not mutate ``ids`` afterwards)."""
+        self._pending_ready.append((ids, t))
+
+    @property
+    def t_ready(self) -> np.ndarray:
+        """When each registry app was handed to dispatch (NaN for every
+        other drop)."""
+        if self._t_ready is None:
+            self._t_ready = np.full(self.pgt.num_drops, np.nan,
+                                    dtype=np.float64)
+        pending, self._pending_ready = self._pending_ready, []
+        for ids, t in pending:
+            self._t_ready[ids] = t
+        return self._t_ready
+
+    def begin_execute(self) -> float:
+        """Open this session's span of one ``execute_frontier`` call."""
+        emit_anchor(session=self.session_id, at="enter")
+        t0 = time.monotonic()
+        self.exec_spans.append((t0, float("nan")))
+        return t0
+
+    def end_execute(self, t0: float) -> None:
+        """Close the span ``begin_execute`` opened at ``t0``."""
+        self.exec_spans[-1] = (t0, time.monotonic())
+        emit_anchor(session=self.session_id, at="exit")
+
+    def causes(self) -> Tuple[np.ndarray, np.ndarray]:
+        """What activated each readied app: ``(cause, cause_end)``.
+
+        ``cause[i]`` is the app whose ``t_end`` completed app ``i``'s
+        last input: the latest-ending producer of its input data drops
+        (or an app wired to it directly).  For an app no other app feeds
+        (a source app) it is -1, and ``cause_end[i]`` is the start of
+        the execute span that readied it; otherwise ``cause_end[i]`` is
+        ``t_end[cause[i]]``.  Drops never readied read -1 and NaN."""
+        t_ready, t_end = self.t_ready, self.t_end
+        n = self.pgt.num_drops
+        cause = np.full(n, -1, dtype=np.int64)
+        cause_end = np.full(n, np.nan, dtype=np.float64)
+        ids = np.flatnonzero(~np.isnan(t_ready))
+        if ids.size == 0:
+            return cause, cause_end
+        indptr, cols = self.pgt.in_csr()
+        ins, cnt = csr_gather_with_counts(indptr, cols, ids)
+        dst = np.repeat(ids, cnt)
+        data = self.pgt.kind_arr[ins] == KIND_DATA
+        up, cnt2 = csr_gather_with_counts(indptr, cols, ins[data])
+        prod = np.concatenate((ins[~data], up))
+        cons = np.concatenate((dst[~data], np.repeat(dst[data], cnt2)))
+        if cons.size:
+            end = t_end[prod]
+            order = np.lexsort((np.where(np.isnan(end), -np.inf, end),
+                                cons))
+            last = order[np.r_[np.flatnonzero(np.diff(cons[order])),
+                               cons.size - 1]]
+            cause[cons[last]] = prod[last]
+            cause_end[cons[last]] = end[last]
+        src = ids[cause[ids] < 0]
+        if src.size and self.exec_spans:
+            starts = np.array([s for s, _ in self.exec_spans])
+            k = np.searchsorted(starts, t_ready[src], side="right") - 1
+            cause_end[src] = starts[np.maximum(k, 0)]
+        return cause, cause_end
 
     def stamp_chunk(self, i: int, seq: int, t0: float, t1: float) -> None:
         """Record one processed stream chunk (consumer ``i``, chunk
@@ -241,6 +342,77 @@ class Timeline:
     def stamped(self) -> np.ndarray:
         """Ids of drops that have been stamped (wave >= 0)."""
         return np.flatnonzero(self.wave >= 0)
+
+
+# ---------------------------------------------------------------------------
+# Clock anchors: Timeline stamps on a jax.profiler trace's clock
+# ---------------------------------------------------------------------------
+
+ANCHOR = "engine.anchor"
+
+
+def emit_anchor(**args: Any) -> None:
+    """Put one ``engine.anchor`` instant, carrying ``mono_ns`` (this
+    process's ``time.monotonic_ns()``) and ``args``, on a running
+    ``jax.profiler`` trace.  Does nothing in a process that has not
+    imported JAX: the engine never loads it."""
+    mono_ns = time.monotonic_ns()
+    if "jax" not in sys.modules:
+        return
+    from jax.profiler import TraceAnnotation
+    with TraceAnnotation(ANCHOR, mono_ns=mono_ns, **args):
+        pass
+
+
+def read_anchors(xspace: Union[str, Path]) -> List[Tuple[int, float]]:
+    """``(mono_ns, trace_ns)`` of every ``engine.anchor`` in a saved
+    profile (the ``*.xplane.pb`` that ``jax.profiler`` writes), in trace
+    order; ``trace_ns`` is on the clock of the profile's other events."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(str(xspace)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == ANCHOR:
+                    stats = {k: v for k, v in e.stats}
+                    out.append((int(stats["mono_ns"]), float(e.start_ns)))
+    return sorted(out, key=lambda a: a[1])
+
+
+@dataclass(frozen=True)
+class ClockFit:
+    """A line from monotonic time to a trace's nanoseconds:
+    ``trace_ns = trace0_ns + offset_ns + slope * (mono_ns - mono0_ns)``.
+    ``residual_ns`` is the largest distance of an anchor from it."""
+
+    mono0_ns: int
+    trace0_ns: float
+    slope: float
+    offset_ns: float
+    residual_ns: float
+
+    def to_trace_ns(self, t: Union[float, np.ndarray]
+                    ) -> Union[float, np.ndarray]:
+        """A ``Timeline`` stamp (monotonic seconds) on the trace's clock."""
+        return self.trace0_ns + self.offset_ns + self.slope * (
+            np.asarray(t, dtype=np.float64) * 1e9 - self.mono0_ns)
+
+
+def fit_clock(anchors: Sequence[Tuple[int, float]]) -> ClockFit:
+    """Least-squares line through ``(mono_ns, trace_ns)`` anchor pairs
+    (two or more, at distinct instants), drift included.  Both clocks
+    are taken relative to the first anchor, so float64 keeps them to the
+    nanosecond."""
+    if len(anchors) < 2:
+        raise ValueError(f"a clock fit needs two anchors, got {len(anchors)}")
+    mono0, trace0 = int(anchors[0][0]), float(anchors[0][1])
+    x = np.array([int(m) - mono0 for m, _ in anchors], dtype=np.float64)
+    y = np.array([t for _, t in anchors], dtype=np.float64) - trace0
+    if np.ptp(x) == 0:
+        raise ValueError("the anchors share one instant")
+    slope, offset = np.polyfit(x, y, 1)
+    resid = float(np.abs(offset + slope * x - y).max())
+    return ClockFit(mono0, trace0, float(slope), float(offset), resid)
 
 
 # ---------------------------------------------------------------------------

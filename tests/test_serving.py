@@ -300,6 +300,25 @@ def test_manager_validates_limits():
         EngineManager(max_pending=-1)
 
 
+def test_latency_counts_the_managers_own_work(monkeypatch):
+    """``queue_delay`` and ``latency`` start at the call to ``submit``, so
+    a slow ``materialize`` inside it shows in both."""
+    from repro.core import GraphTemplate
+    slow = 0.2
+    materialize = GraphTemplate.materialize
+
+    def slow_materialize(self, *args, **kwargs):
+        time.sleep(slow)
+        return materialize(self, *args, **kwargs)
+
+    monkeypatch.setattr(GraphTemplate, "materialize", slow_materialize)
+    with EngineManager(num_nodes=2, workers_per_node=2) as m:
+        t = m.submit(simple_lg("srvslow"), inputs={"in": 1})
+        assert t.result(30).ok
+        assert t.queue_delay >= slow
+        assert t.latency >= t.queue_delay
+
+
 # ---------------------------------------------------------------------------
 # session lifecycle: close + eviction
 # ---------------------------------------------------------------------------

@@ -4,6 +4,9 @@
   arrays: stamped for every terminal drop, consistent along edges,
   lazily allocated (off = no arrays at all, on = nothing allocated
   until first read);
+* **waits** — ``t_ready``, the activating app and the execute spans:
+  the serving graph's worker wait and wave-barrier delay, source apps,
+  resumes, and ``engine.anchor`` on a profiler trace's clock;
 * **metrics** — the lock-cheap registry: unit semantics, thread
   safety, the scheduler / EngineManager / resilience wiring (incl.
   N temporally-concurrent manager sessions sharing one registry);
@@ -14,8 +17,12 @@
   final ``on_wave`` report where consumers observe completed == total.
 """
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -24,8 +31,10 @@ from repro.core import (EngineManager, AdmissionError, GraphTemplate,
                         MetricsRegistry, Pipeline, ResilienceConfig,
                         RetryPolicy, TelemetryConfig, execute_frontier,
                         export_chrome_trace, make_cluster, register_app)
+from repro.core import telemetry
 from repro.core.exec_compiled import ExecHooks
-from repro.core.telemetry import Counter, Gauge, Histogram
+from repro.core.telemetry import (ClockFit, Counter, Gauge, Histogram,
+                                  fit_clock, read_anchors)
 from repro.dsl import GraphBuilder
 
 TEL = TelemetryConfig(timeline=True, metrics=True)
@@ -193,12 +202,25 @@ class TestTimeline:
             assert tl.wave[i] >= 0
             assert np.isfinite(tl.t_end[i])
 
-    def test_off_by_default_allocates_nothing(self):
+    def test_off_by_default_allocates_nothing(self, monkeypatch):
+        anchors = []
+        monkeypatch.setattr(telemetry, "emit_anchor",
+                            lambda **kw: anchors.append(kw))
         with Pipeline(num_nodes=1, execution="compiled") as p:
             rep = p.run(chain_lg("teloff"), inputs={"src": 1})
             assert rep.ok
             assert p.session.timeline is None
             assert p.session.metrics is None
+        with EngineManager(num_nodes=2, workers_per_node=2) as mgr:
+            t = mgr.submit(serving_lg("teloffmgr"), inputs={"reqs": 0})
+            assert t.result(30).ok
+            assert t.session.timeline is None
+        assert anchors == []
+        # the same run with the timeline on: one anchor per call edge and
+        # one per registry app (the noop is a fast path)
+        with Pipeline(num_nodes=1, execution="compiled", telemetry=TEL) as p:
+            assert p.run(chain_lg("telon"), inputs={"src": 1}).ok
+        assert [a["at"] for a in anchors] == ["enter", "app", "exit"]
 
     def test_arrays_allocate_lazily_on_first_read(self):
         # the fast-path run must not allocate the big arrays (cache
@@ -213,6 +235,176 @@ class TestTimeline:
             stamped = tl.stamped()              # forces replay
             assert not tl._pending
             assert stamped.size == p.session.pgt.num_drops
+
+
+# ---------------------------------------------------------------------------
+# where the time between apps goes: t_ready, causes, execute spans
+# ---------------------------------------------------------------------------
+
+PREFILL_S, DECODE_S = 0.05, 0.2
+
+
+def _sleeper(secs):
+    def app(inputs, outputs, app):
+        time.sleep(secs)
+        for o in outputs:
+            o.write(secs)
+    return app
+
+
+register_app("tel_prefill")(_sleeper(PREFILL_S))
+register_app("tel_decode")(_sleeper(DECODE_S))
+register_app("tel_assemble")(_sleeper(0.0))
+
+
+def serving_lg(name="telserve", n_micro=2):
+    """The LM serving graph's shape (``reqs`` scattered into microbatches
+    of prefill -> decode, gathered by assemble), with sleeping apps."""
+    g = GraphBuilder(name)
+    g.data("reqs")
+    with g.scatter("mb", n_micro):
+        g.component("prefill", app="tel_prefill", time=0.5)
+        g.data("kv", volume=1e6)
+        g.component("decode", app="tel_decode", time=1.0)
+        g.data("gen")
+    with g.gather("all", n_micro):
+        g.component("assemble", app="tel_assemble", time=0.01)
+    g.data("responses")
+    g.chain("reqs", "prefill", "kv", "decode", "gen")
+    g.connect("gen", "assemble")
+    g.chain("assemble", "responses")
+    return g.graph()
+
+
+@pytest.fixture(scope="module")
+def served():
+    """One serving session through the manager, as the benchmark runs it:
+    2 nodes x 2 workers, 2 sessions at once, timeline on."""
+    with EngineManager(num_nodes=2, workers_per_node=2, max_concurrent=2,
+                       telemetry=TelemetryConfig(timeline=True)) as mgr:
+        t = mgr.submit(serving_lg(), inputs={"reqs": 0}, timeout=30)
+        assert t.result(30).ok
+        s = t.session
+        tl = s.timeline
+        yield s, tl, {s.pgt.uid_of(i): i for i in range(s.pgt.num_drops)}
+
+
+class TestWaits:
+    def test_same_node_microbatch_waits_one_app_wall(self, served):
+        s, tl, ix = served
+        apps = [ix[f"{g}#{m}"] for g in ("prefill", "decode")
+                for m in (0, 1)]
+        # the mapper puts the whole session on one node, and a node's
+        # batch of a wave runs in one worker, one app after another
+        assert len(set(s.pgt.node_ids[apps].tolist())) == 1
+        wait = tl.t_start - tl.t_ready
+        wall = tl.t_end - tl.t_start
+        for g in ("prefill", "decode"):
+            first, second = ix[f"{g}#0"], ix[f"{g}#1"]
+            assert wait[second] == pytest.approx(wall[first], abs=0.02)
+            assert wait[first] < 0.02
+        assert wall[ix["decode#0"]] >= DECODE_S
+
+    def test_wave_barrier_shows_as_activation_delay(self, served):
+        _, tl, ix = served
+        cause, cause_end = tl.causes()
+        d0 = ix["decode#0"]
+        # decode#0's input was done when prefill#0 ended, but the wave
+        # went on until prefill#1 ended
+        assert cause[d0] == ix["prefill#0"]
+        assert cause_end[d0] == tl.t_end[ix["prefill#0"]]
+        delay = tl.t_ready[d0] - cause_end[d0]
+        assert delay == pytest.approx(PREFILL_S, abs=0.02)
+        assert cause[ix["decode#1"]] == ix["prefill#1"]
+        # the gather is activated by the later decode
+        assert cause[ix["assemble#0"]] == ix["decode#1"]
+
+    def test_source_app_cause_is_execute_start(self, served):
+        _, tl, ix = served
+        cause, cause_end = tl.causes()
+        (t0, t1), = tl.exec_spans
+        for m in (0, 1):
+            i = ix[f"prefill#{m}"]
+            assert cause[i] == -1 and cause_end[i] == t0
+        assert t0 <= np.nanmin(tl.t_ready) and np.nanmax(tl.t_end) <= t1
+        # data drops are never readied
+        assert np.isnan(tl.t_ready[ix["kv#0"]])
+        assert np.isnan(cause_end[ix["kv#0"]])
+
+    def test_resumed_session_keeps_one_span_per_call(self):
+        master, nodes = make_cluster(1, 1, 2)
+        try:
+            tpl = GraphTemplate.build(serving_lg("telresume"), nodes, dop=4)
+            s = tpl.materialize("resumed", master=master)
+            s.enable_timeline()
+            s.write("reqs", 0)
+
+            def stop_at_decode(sess, done, total):
+                if done and not calls:
+                    calls.append(done)
+                    raise RuntimeError("stop")
+            calls = []
+            hooks = ExecHooks(on_wave=stop_at_decode)
+            with pytest.raises(RuntimeError, match="stop"):
+                execute_frontier(s, timeout=30, hooks=hooks)
+            assert execute_frontier(s, timeout=30, hooks=hooks)
+            tl = s.timeline
+            assert len(tl.exec_spans) == 2
+            (a0, a1), (b0, b1) = tl.exec_spans
+            assert a0 < a1 <= b0 < b1
+            # an app readied in the second call has its own span's start
+            # or a producer as its cause, never the first span's start
+            cause, cause_end = tl.causes()
+            ready = np.flatnonzero(tl.t_ready >= b0)
+            assert ready.size and np.all(cause_end[ready] >= a0)
+            src = ready[cause[ready] == -1]
+            assert np.all(cause_end[src] == b0)
+        finally:
+            master.shutdown()
+
+    def test_anchors_put_the_timeline_on_the_trace_clock(self, tmp_path):
+        import jax
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with EngineManager(num_nodes=2, workers_per_node=2,
+                               telemetry=TelemetryConfig(timeline=True)
+                               ) as mgr:
+                tickets = [mgr.submit(serving_lg("telanchor"),
+                                      inputs={"reqs": k}, timeout=30)
+                           for k in range(2)]
+                assert all(t.result(30).ok for t in tickets)
+        finally:
+            jax.profiler.stop_trace()
+        anchors = read_anchors(next(tmp_path.rglob("*.xplane.pb")))
+        # enter, exit and 5 registry apps per session
+        assert len(anchors) == 2 * (2 + 5)
+        fit = fit_clock(anchors)
+        assert abs(fit.slope - 1.0) < 1e-3
+        assert fit.residual_ns < 1e6
+        for t in tickets:
+            (t0, t1), = t.session.timeline.exec_spans
+            m0, m1 = fit.to_trace_ns(np.array([t0, t1]))
+            assert m0 < m1 and m1 - m0 == pytest.approx((t1 - t0) * 1e9,
+                                                         rel=1e-3)
+
+    def test_clock_fit_recovers_offset_and_drift(self):
+        mono = [10**15 + k * 10**9 for k in range(5)]
+        trace = [123.0 + 1.00002 * (m - mono[0]) for m in mono]
+        fit = fit_clock(list(zip(mono, trace)))
+        assert isinstance(fit, ClockFit)
+        assert fit.slope == pytest.approx(1.00002, rel=1e-9)
+        assert fit.residual_ns < 1e-3
+        assert fit.to_trace_ns(mono[2] / 1e9) == pytest.approx(trace[2],
+                                                               abs=1.0)
+        with pytest.raises(ValueError):
+            fit_clock(list(zip(mono, trace))[:1])
+
+    def test_importing_core_does_not_load_jax(self):
+        src = Path(__file__).resolve().parents[1] / "src"
+        env = dict(os.environ, PYTHONPATH=str(src))
+        code = "import sys, repro.core; sys.exit('jax' in sys.modules)"
+        assert subprocess.run([sys.executable, "-c", code], env=env,
+                              timeout=120).returncode == 0
 
 
 # ---------------------------------------------------------------------------
