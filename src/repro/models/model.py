@@ -614,7 +614,8 @@ def _prime_ssm(params, cfg, x, positions, cache, use_kernel):
         y = rms_norm(y.reshape(B, S, di) * jax.nn.silu(z), p["norm"])
         out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"])
         return out, {"conv": new_conv.astype(c["conv"].dtype),
-                     "state": state.astype(c["state"].dtype)}
+                     "state": jnp.swapaxes(state, -1, -2).astype(
+                         c["state"].dtype)}
 
     if cfg.family == "ssm":
         def body(h, layer):

@@ -158,12 +158,19 @@ def mamba2_forward(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig,
 
 def init_ssm_cache(cfg: ArchConfig, batch: int, dtype: Any
                    ) -> Dict[str, jax.Array]:
+    """Conv window (B, W-1, C) and SSM state (B, h, headdim, d_state).
+
+    The state is stored with d_state minor, the order the decode step's
+    update and read-out contract in, so the TPU keeps it in one layout
+    across steps; ``ssd_chunked``'s (B, h, d_state, headdim) final state
+    is transposed once at prefill.
+    """
     di, n, g = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_groups
     h, P = cfg.ssm_heads, cfg.ssm_headdim
     conv_ch = di + 2 * g * n
     return {
         "conv": jnp.zeros((batch, cfg.ssm_conv - 1, conv_ch), dtype),
-        "state": jnp.zeros((batch, h, n, P), dtype),
+        "state": jnp.zeros((batch, h, P, n), dtype),
     }
 
 
@@ -192,9 +199,9 @@ def mamba2_decode_step(p: Dict[str, jax.Array], x: jax.Array,
     ch = jnp.repeat(c_.reshape(B, g, n), rep, axis=1)
     xh = xin.reshape(B, h, P)
     state = (cache["state"] * decay[..., None, None]
-             + jnp.einsum("bhk,bhp->bhkp",
-                          bh * dt[..., None], xh).astype(cache["state"].dtype))
-    y = jnp.einsum("bhk,bhkp->bhp", ch, state.astype(jnp.float32))
+             + jnp.einsum("bhp,bhk->bhpk", xh,
+                          bh * dt[..., None]).astype(cache["state"].dtype))
+    y = jnp.einsum("bhpk,bhk->bhp", state.astype(jnp.float32), ch)
     y = y + xh.astype(jnp.float32) * p["D"][None, :, None]
     y = y.reshape(B, 1, di).astype(x.dtype)
     y = rms_norm(y * jax.nn.silu(z), p["norm"])

@@ -238,7 +238,7 @@ def batch_pspecs(cfg: ArchConfig, batch_abstract: Dict[str, Any],
 
 
 def cache_pspecs(cfg: ArchConfig, cache_abstract: Any, mesh: Mesh) -> Any:
-    """KV: (L,B,T,kv,hd); SSM state: (L,B,h,n,p); conv: (L,B,W,C)."""
+    """KV: (L,B,T,kv,hd); SSM state: (L,B,h,p,n); conv: (L,B,W,C)."""
     decisions: List[str] = []
 
     def kv_spec(leaf):
@@ -256,8 +256,8 @@ def cache_pspecs(cfg: ArchConfig, cache_abstract: Any, mesh: Mesh) -> Any:
             return kv_spec(leaf)
         if "'k'" in path or "'v'" in path:
             return kv_spec(leaf)
-        if "state" in path:                          # (L,B,h,n,p)
-            L, B, H, N, Pdim = leaf.shape
+        if "state" in path:                          # (L,B,h,p,n)
+            L, B, H, Pdim, N = leaf.shape
             return P(None, _batch_axis(mesh, B),
                      shard_if_divisible(H, mesh, "model", decisions,
                                         "ssm-heads"), None, None)

@@ -14,6 +14,25 @@ from repro.models.common import SHAPES
 KEY = jax.random.PRNGKey(0)
 B, S = 2, 16
 
+# The SSM smoke configs have d_state == headdim, where a state stored in the
+# wrong order still has the right shape; these cases widen d_state.
+WIDE_STATE = {"mamba2_1_3b-wide_state": "mamba2_1_3b",
+              "zamba2_2_7b-wide_state": "zamba2_2_7b"}
+
+
+def smoke_config(case):
+    if case in WIDE_STATE:
+        return dataclasses.replace(get_smoke_config(WIDE_STATE[case]),
+                                   ssm_state=32)
+    return get_smoke_config(case)
+
+
+def assert_state_layout(cfg, cache):
+    """The SSM state is stored (L, B, heads, headdim, d_state)."""
+    if cfg.family in ("ssm", "hybrid"):
+        assert cache["ssm"]["state"].shape == (
+            cfg.num_layers, B, cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state)
+
 
 def make_batch(cfg, with_labels=True):
     batch = {"tokens": jax.random.randint(KEY, (B, S), 0, cfg.vocab_size)}
@@ -27,10 +46,10 @@ def make_batch(cfg, with_labels=True):
     return batch
 
 
-@pytest.mark.parametrize("arch", ARCH_NAMES)
+@pytest.mark.parametrize("arch", ARCH_NAMES + list(WIDE_STATE))
 class TestSmoke:
     def test_train_step_shapes_and_finite(self, arch):
-        cfg = get_smoke_config(arch)
+        cfg = smoke_config(arch)
         params = M.init_params(cfg, KEY)
         batch = make_batch(cfg)
         loss, parts = jax.jit(
@@ -41,7 +60,7 @@ class TestSmoke:
         assert bool(jnp.isfinite(parts["loss"]))
 
     def test_train_step_with_remat_matches(self, arch):
-        cfg = get_smoke_config(arch)
+        cfg = smoke_config(arch)
         params = M.init_params(cfg, KEY)
         batch = make_batch(cfg)
         l1, _ = jax.jit(lambda p, b: M.forward_train(p, cfg, b,
@@ -54,7 +73,7 @@ class TestSmoke:
                                    rtol=1e-5)
 
     def test_decode_matches_prefill(self, arch):
-        cfg = get_smoke_config(arch)
+        cfg = smoke_config(arch)
         params = M.init_params(cfg, KEY)
         batch = make_batch(cfg, with_labels=False)
         logits_full, primed = jax.jit(
@@ -68,12 +87,14 @@ class TestSmoke:
         for i in range(S):
             logits_i, cache = step(params, cache, toks[:, i:i + 1],
                                    jnp.int32(i))
+        assert_state_layout(cfg, primed)
+        assert_state_layout(cfg, cache)
         diff = float(jnp.max(jnp.abs(logits_i[:, 0] - logits_full[:, 0])))
         assert diff < 2e-2, (arch, diff)
 
     def test_decode_continues_from_primed_cache(self, arch):
         """prefill cache + decode of one extra token == decode-from-scratch."""
-        cfg = get_smoke_config(arch)
+        cfg = smoke_config(arch)
         params = M.init_params(cfg, KEY)
         toks = jax.random.randint(KEY, (B, S + 1), 0, cfg.vocab_size)
         batch = {"tokens": toks[:, :S]}
@@ -90,8 +111,10 @@ class TestSmoke:
             return jnp.pad(src, pad).astype(dst.dtype)
         primed_grown = jax.tree.map(fill, grown, primed)
         step = jax.jit(lambda p, c, t, pos: M.decode_step(p, cfg, c, t, pos))
-        l_primed, _ = step(params, primed_grown, toks[:, S:S + 1],
-                           jnp.int32(S))
+        assert_state_layout(cfg, primed)
+        l_primed, after = step(params, primed_grown, toks[:, S:S + 1],
+                               jnp.int32(S))
+        assert_state_layout(cfg, after)
 
         scratch = M.init_cache(cfg, B, S + 1)
         if cfg.family == "encdec":

@@ -1,9 +1,12 @@
-"""Chip compiles of the Pallas kernels at the widths the models run them at.
+"""Chip compiles of the Pallas kernels and of a serving step, at the widths
+the models run them at.
 
-Each test lowers a kernel with ``interpret=False`` for one chip of a
-described (not attached) TPU v5e topology and checks that Mosaic accepted it:
-the compiled program carries a ``tpu_custom_call``.  Nothing runs; this is
-what the chip's compiler would refuse, caught without the chip.
+Each test lowers a program for one chip of a described (not attached) TPU
+v5e topology.  A kernel, lowered with ``interpret=False``, must be accepted
+by Mosaic: the compiled program carries a ``tpu_custom_call``.  The decode
+step must keep its cache's layout: the compiled program is read for copies
+and temporaries.  Nothing runs; this is what the chip's compiler would
+refuse or re-lay out, caught without the chip.
 
 The topology is described inside a module fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -11,6 +14,7 @@ this file.  The persistent compilation cache is off around these compiles,
 since an entry compiled for a described chip cannot be read back here.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +23,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import flash_attention_bhsd, ssd_scan_bhsd
+from repro.models import model as M
+from repro.train.steps import make_decode_step
 
 
 @pytest.fixture(scope="module")
@@ -74,3 +80,35 @@ def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
         x, dt, a, b, c, cfg.ssm_chunk, interpret=False)).lower(
             x, dt, a, bc, bc).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mamba2_decode_step_keeps_the_state_layout(one_chip):
+    """The decode step at the served microbatch, with the float32 state of
+    every step after the first, updates each layer's state in the layout
+    the cache stores: no copy re-lays out a layer's state, and no
+    temporary holds one (the state stays in the output stack)."""
+    cfg = get_config("mamba2_1_3b")
+    mb = 16
+    h, p, n = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
+
+    def spec(path, a):
+        state = "state" in jax.tree_util.keystr(path)
+        return _spec(a.shape, jnp.float32 if state else a.dtype, one_chip)
+    params = jax.eval_shape(lambda k: M.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map_with_path(spec, params)
+    cache = jax.tree_util.tree_map_with_path(
+        spec, jax.eval_shape(lambda: M.init_cache(cfg, mb, 1)))
+    assert cache["ssm"]["state"].shape == (cfg.num_layers, mb, h, p, n)
+    tokens = _spec((mb, 1), jnp.int32, one_chip)
+    pos = _spec((), jnp.int32, one_chip)
+    compiled = jax.jit(make_decode_step(cfg)).lower(
+        params, cache, tokens, pos).compile()
+
+    layer_state = re.compile(
+        rf"= f32\[(1,)?{mb},{h},({p},{n}|{n},{p})\]\S* copy\(")
+    copies = [line.strip() for line in compiled.as_text().splitlines()
+              if layer_state.search(line)]
+    assert not copies, copies
+    one_layer = mb * h * p * n * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer
