@@ -1,7 +1,9 @@
-"""granite-moe-3b-a800m [moe] — 40 experts top-8.
+"""granite-moe-3b-a800m [moe] — 40 experts top-8, dropless.
 
-32L d_model=1536 24H (GQA kv=8) d_ff=512 vocab=49155, MoE 40e top-8
-[hf:ibm-granite/granite-3.0-1b-a400m-base; hf].
+32L d_model=1536 24H (GQA kv=8) d_ff=512 vocab=49155, MoE 40e top-8,
+tied embeddings, Granite's multipliers (embedding 12, attention 1/64,
+residual 0.22, logits /6) [hf:ibm-granite/granite-3.0-3b-a800m-base,
+``model_type`` granitemoe; the published MoE drops no token].
 """
 from ..models.common import ArchConfig
 
@@ -9,8 +11,10 @@ CONFIG = ArchConfig(
     name="granite-moe-3b-a800m", family="moe",
     num_layers=32, d_model=1536, num_heads=24, num_kv_heads=8, head_dim=64,
     d_ff=512, vocab_size=49155,
-    num_experts=40, top_k=8,
-    activation="swiglu",
+    num_experts=40, top_k=8, moe_dropless=True,
+    embedding_multiplier=12.0, attention_multiplier=0.015625,
+    residual_multiplier=0.22, logits_scaling=6.0,
+    activation="swiglu", tie_embeddings=True,
     sharding_strategy="dp",
     notes="fine-grained MoE (40e top-8); heads 24 and kv 8 don't divide "
           "tp16 -> attention replicated across model axis (baseline)",
@@ -20,6 +24,8 @@ SMOKE = ArchConfig(
     name="granite-smoke", family="moe",
     num_layers=2, d_model=64, num_heads=4, num_kv_heads=2, head_dim=16,
     d_ff=64, vocab_size=256,
-    num_experts=8, top_k=4,
-    activation="swiglu", dtype="float32",
+    num_experts=8, top_k=4, moe_dropless=True,
+    embedding_multiplier=12.0, attention_multiplier=0.015625,
+    residual_multiplier=0.22, logits_scaling=6.0,
+    activation="swiglu", tie_embeddings=True, dtype="float32",
 )

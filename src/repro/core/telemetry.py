@@ -462,6 +462,11 @@ class Gauge:
         with self._lock:
             self._value -= n
 
+    def set_max(self, v: float) -> None:
+        """Raise the value to ``v`` if ``v`` is larger (a running max)."""
+        with self._lock:
+            self._value = max(self._value, v)
+
     @property
     def value(self) -> float:
         return self._value

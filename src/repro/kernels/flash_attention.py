@@ -84,15 +84,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = True, window: int = 0,
                          logit_cap: float = 0.0,
+                         scale: Optional[float] = None,
                          block_q: int = DEFAULT_BLOCK_Q,
                          block_k: int = DEFAULT_BLOCK_K,
                          interpret: bool) -> jax.Array:
-    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D) -> (B, Hq, Sq, D)."""
+    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D) -> (B, Hq, Sq, D).
+
+    ``scale`` multiplies the scores; None is ``1/sqrt(D)``."""
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
     assert hq % hkv == 0, (hq, hkv)
     group = hq // hkv
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
 
     block_q = min(block_q, max(sq, 8))
     block_k = min(block_k, max(sk, 8))

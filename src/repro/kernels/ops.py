@@ -32,14 +32,16 @@ def _interpret() -> bool:
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
-                    logit_cap: float = 0.0,
+                    logit_cap: float = 0.0, scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128) -> jax.Array:
-    """Model layout (B,S,H,D) in/out; kernel runs (B,H,S,D)."""
+    """Model layout (B,S,H,D) in/out; kernel runs (B,H,S,D).  ``scale``
+    multiplies the scores (None: ``1/sqrt(D)``)."""
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     out = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
-                               logit_cap=logit_cap, block_q=block_q,
+                               logit_cap=logit_cap, scale=scale,
+                               block_q=block_q,
                                block_k=block_k, interpret=_interpret())
     return jnp.swapaxes(out, 1, 2)
 

@@ -10,15 +10,18 @@ import jax.numpy as jnp
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, window: int = 0,
-                  logit_cap: float = 0.0) -> jax.Array:
-    """q: (B,Hq,Sq,D); k/v: (B,Hkv,Sk,D) -> (B,Hq,Sq,D).  GQA by head map."""
+                  logit_cap: float = 0.0,
+                  scale: Optional[float] = None) -> jax.Array:
+    """q: (B,Hq,Sq,D); k/v: (B,Hkv,Sk,D) -> (B,Hq,Sq,D).  GQA by head map;
+    ``scale`` multiplies the scores (None: ``1/sqrt(D)``)."""
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
     group = hq // hkv
     kx = jnp.repeat(k, group, axis=1)
     vx = jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   kx.astype(jnp.float32)) / math.sqrt(d)
+                   kx.astype(jnp.float32))
+    s = s / math.sqrt(d) if scale is None else s * scale
     if logit_cap:
         s = logit_cap * jnp.tanh(s / logit_cap)
     rows = jnp.arange(sq)[:, None]

@@ -81,6 +81,17 @@ def prefill_microbatch(cfg: ArchConfig, params: Any, chunk: np.ndarray,
     return next_tok[:, None], jax.tree.map(fill, grown, cache)
 
 
+def _record_moe_counts(metrics: Any, counts: Dict[str, Any]) -> None:
+    """Add one decode app's MoE routing counters (its cache's ``moe``
+    entry, ``models/model.py:init_moe_counts``) to ``metrics``; one read
+    from the device."""
+    c = jax.device_get(counts)
+    metrics.counter("moe.rows_routed").inc(int(c["rows"].sum()))
+    metrics.counter("moe.layer_steps").inc(int(c["steps"].sum()))
+    metrics.counter("moe.experts_touched").inc(int(c["touched"].sum()))
+    metrics.gauge("moe.expert_rows_max").set_max(int(c["rows_max"].max()))
+
+
 def reference_tokens(cfg: ArchConfig, params: Any, prompts: np.ndarray, *,
                      microbatch: int, decode_steps: int) -> np.ndarray:
     """The plain reference of the serving graph: the same prefill and
@@ -140,6 +151,14 @@ def run_serving(cfg: ArchConfig, *, num_requests: int = 8,
         params = M.init_params(cfg, jax.random.PRNGKey(0))
     _, decode_one = serving_steps(cfg)
     prompts = make_prompts(cfg, num_requests, prompt_len)
+    # the engine's metrics registry, set where the engine is made (None
+    # with metrics off); an MoE model's decode apps add their routing
+    # counters to it
+    registry = None
+
+    def count_moe(cache):
+        if registry is not None and "moe" in cache:
+            _record_moe_counts(registry, cache["moe"])
 
     @register_app("serve/prefill", device=True)
     def prefill_app(inputs, outputs, app):
@@ -159,6 +178,7 @@ def run_serving(cfg: ArchConfig, *, num_requests: int = 8,
             tok, cache = decode_one(params, cache, tok,
                                     jnp.int32(prompt_len + i))
             toks.append(tok)
+        count_moe(cache)
         for o in outputs:
             o.write(np.asarray(jnp.concatenate(toks, axis=1)))
 
@@ -177,6 +197,7 @@ def run_serving(cfg: ArchConfig, *, num_requests: int = 8,
                                     jnp.int32(prompt_len + i))
             for o in outputs:
                 o.write((mb, i + 1, np.asarray(tok)))
+        count_moe(cache)
 
     @register_app("serve/assemble")
     def assemble(inputs, outputs, app):
@@ -215,18 +236,22 @@ def run_serving(cfg: ArchConfig, *, num_requests: int = 8,
     g.connect("gen", "assemble", streaming=streaming)
     g.chain("assemble", "responses")
 
-    if sessions > 1:
-        return _run_sessions(g.graph(), sessions=sessions,
-                             num_nodes=num_nodes,
-                             max_concurrent=max_concurrent,
-                             num_requests=num_requests,
-                             decode_steps=decode_steps,
-                             stats_json=stats_json)
-
     telemetry = TelemetryConfig(metrics=True) if stats_json else None
+    if sessions > 1:
+        with EngineManager(num_nodes=num_nodes, workers_per_node=2,
+                           max_concurrent=max_concurrent,
+                           max_pending=sessions,
+                           telemetry=telemetry) as mgr:
+            registry = mgr.metrics
+            return _run_sessions(mgr, g.graph(), sessions=sessions,
+                                 num_requests=num_requests,
+                                 decode_steps=decode_steps,
+                                 stats_json=stats_json)
+
     engine_cfg = EngineConfig(num_nodes=num_nodes, workers_per_node=2,
                               execution=execution, telemetry=telemetry)
     with Pipeline(engine_cfg) as p:
+        registry = p.metrics
         p.translate(g.graph())
         p.deploy()
         t0 = time.monotonic()
@@ -258,31 +283,25 @@ def run_serving(cfg: ArchConfig, *, num_requests: int = 8,
     return result
 
 
-def _run_sessions(lg, *, sessions: int, num_nodes: int,
-                  max_concurrent: int, num_requests: int,
-                  decode_steps: int,
+def _run_sessions(mgr: EngineManager, lg, *, sessions: int,
+                  num_requests: int, decode_steps: int,
                   stats_json: Optional[str] = None) -> Dict[str, Any]:
-    """Serve one graph shape ``sessions`` times through a resident
-    EngineManager: one cold translate+map, then cache-hit sessions that
-    share node pools and run up to ``max_concurrent`` at once."""
-    telemetry = TelemetryConfig(metrics=True) if stats_json else None
-    with EngineManager(num_nodes=num_nodes, workers_per_node=2,
-                       max_concurrent=max_concurrent,
-                       max_pending=sessions,
-                       telemetry=telemetry) as mgr:
-        t0 = time.monotonic()
-        tickets = [mgr.submit(lg, inputs={"reqs": num_requests},
-                              timeout=3600, block=True)
-                   for _ in range(sessions)]
-        reports = [t.result() for t in tickets]
-        wall = time.monotonic() - t0
-        for rep in reports:
-            assert rep.ok, rep.errors[:3]
-        out = tickets[-1].session.read("responses")
-        lats = sorted(t.latency for t in tickets)
-        stats = mgr.stats()
-        if stats_json:
-            _dump_stats(stats_json, stats)
+    """Serve one graph shape ``sessions`` times through the resident
+    ``mgr``: one cold translate+map, then cache-hit sessions that share
+    node pools and run up to its ``max_concurrent`` at once."""
+    t0 = time.monotonic()
+    tickets = [mgr.submit(lg, inputs={"reqs": num_requests},
+                          timeout=3600, block=True)
+               for _ in range(sessions)]
+    reports = [t.result() for t in tickets]
+    wall = time.monotonic() - t0
+    for rep in reports:
+        assert rep.ok, rep.errors[:3]
+    out = tickets[-1].session.read("responses")
+    lats = sorted(t.latency for t in tickets)
+    stats = mgr.stats()
+    if stats_json:
+        _dump_stats(stats_json, stats)
     gen_tokens = sessions * num_requests * decode_steps
     result = {
         "responses": out,

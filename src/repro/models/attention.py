@@ -74,6 +74,8 @@ def _gqa_scores(q: jax.Array, k: jax.Array, cfg: ArchConfig) -> jax.Array:
     qg = q.reshape(b, s, nkv, g, hd)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg.astype(jnp.float32),
                         k.astype(jnp.float32))
+    if cfg.attention_multiplier is not None:
+        return scores * cfg.attention_multiplier
     return scores / math.sqrt(hd)
 
 
@@ -110,7 +112,8 @@ def attention(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig, *,
         out = kops.flash_attention(
             q, k, v, causal=causal,
             window=int(window) if window is not None else 0,
-            logit_cap=cfg.attn_softcap)
+            logit_cap=cfg.attn_softcap,
+            scale=cfg.attention_multiplier)
     else:
         scores = _gqa_scores(q, k, cfg)
         scores = softcap(scores, cfg.attn_softcap)

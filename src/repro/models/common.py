@@ -53,6 +53,13 @@ class ArchConfig:
     num_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    moe_dropless: bool = False     # grouped matmul over every routed row;
+    #                                capacity_factor is then unused
+    # Granite's multipliers; the defaults are neutral and emit no operation
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None  # None -> 1/sqrt(head_dim)
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0         # logits are divided by it
     # SSM (mamba2)
     ssm_state: int = 0
     ssm_expand: int = 2

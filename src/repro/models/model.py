@@ -159,8 +159,13 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> Dict[str, Any]:
     kg = KeyGen(key)
     dt = _dtype(cfg)
     d, vp = cfg.d_model, cfg.padded_vocab
+    # drawn at 1/embedding_multiplier of the usual scale, so that the
+    # scaled rows enter the residual at the scale an unscaled model's do:
+    # at full scale they would swamp every layer, and with tied embeddings
+    # a random model would then only repeat its last token
+    em = cfg.embedding_multiplier
     params: Dict[str, Any] = {
-        "embed": dense_init(kg(), (vp, d), dt, fan_in=d),
+        "embed": dense_init(kg(), (vp, d), dt, fan_in=d * em * em),
         "final_norm": jnp.zeros((d,), dt),
     }
     if not cfg.tie_embeddings:
@@ -205,6 +210,13 @@ def layer_windows(cfg: ArchConfig, seq_or_cache_len: int) -> Optional[np.ndarray
 # ---------------------------------------------------------------------------
 
 
+def _residual(cfg: ArchConfig, h: jax.Array, y: jax.Array) -> jax.Array:
+    """``h + y`` scaled by the residual multiplier (none at 1)."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return h + y
+
+
 def _dense_body(cfg: ArchConfig, positions, use_kernel, remat: bool):
     def body(carry, layer):
         h, aux = carry
@@ -212,14 +224,14 @@ def _dense_body(cfg: ArchConfig, positions, use_kernel, remat: bool):
         a = attention(p["attn"], rms_norm(h, p["attn_norm"]), cfg,
                       positions=positions, window=window,
                       causal=True, use_kernel=use_kernel)
-        h = h + a
+        h = _residual(cfg, h, a)
         xin = rms_norm(h, p["mlp_norm"])
         if cfg.family == "moe":
-            m, aux_l = moe_block(p["moe"], xin, cfg)
+            m, aux_l, _ = moe_block(p["moe"], xin, cfg)
             aux = aux + aux_l
         else:
             m = _mlp(p["mlp"], xin, cfg)
-        h = sctx.constrain(h + m, "residual")
+        h = sctx.constrain(_residual(cfg, h, m), "residual")
         return (h, aux), None
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
@@ -334,6 +346,8 @@ def encode(params: Dict[str, Any], cfg: ArchConfig,
 def embed_tokens(params: Dict[str, Any], cfg: ArchConfig,
                  tokens: jax.Array) -> jax.Array:
     x = params["embed"][tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if cfg.family == "encdec":
         x = x + sinusoidal_positions(
             tokens.shape[-1], cfg.d_model).astype(x.dtype)
@@ -345,8 +359,10 @@ def logits_fn(params: Dict[str, Any], cfg: ArchConfig,
     h = rms_norm(h, params["final_norm"])
     head = (params["embed"].T if cfg.tie_embeddings
             else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", h, head)
-    return softcap(logits.astype(jnp.float32), cfg.final_softcap)
+    logits = jnp.einsum("bsd,dv->bsv", h, head).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return softcap(logits, cfg.final_softcap)
 
 
 def forward_train(params: Dict[str, Any], cfg: ArchConfig,
@@ -381,7 +397,9 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int) -> Dict[str, Any]:
         return jax.tree.map(
             lambda a: jnp.zeros((n, *a.shape), a.dtype), one)
 
-    if cfg.family in ("dense", "moe", "vlm"):
+    if cfg.family == "moe":
+        return {"kv": stack_kv(L), "moe": init_moe_counts(cfg)}
+    if cfg.family in ("dense", "vlm"):
         return {"kv": stack_kv(L)}
     if cfg.family == "ssm":
         one = init_ssm_cache(cfg, batch, dt)
@@ -408,6 +426,31 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int) -> Dict[str, Any]:
     raise ValueError(cfg.family)
 
 
+def init_moe_counts(cfg: ArchConfig) -> Dict[str, jax.Array]:
+    """The MoE routing counters a decode cache carries, all zero: per
+    layer, the rows routed to each expert summed over steps (``rows``,
+    (L, E)), the experts that got at least one row summed over steps
+    (``touched``, (L,)) and the most rows one expert got in a step
+    (``rows_max``, (L,)); and the steps each layer counted (``steps``,
+    (L,)).  Every leaf has a layer axis, so the cache's leaves all pad and
+    stack alike."""
+    L, E = cfg.num_layers, cfg.num_experts
+    return {"rows": jnp.zeros((L, E), jnp.int32),
+            "touched": jnp.zeros((L,), jnp.int32),
+            "rows_max": jnp.zeros((L,), jnp.int32),
+            "steps": jnp.zeros((L,), jnp.int32)}
+
+
+def _count_moe(counts: Dict[str, jax.Array],
+               rows: jax.Array) -> Dict[str, jax.Array]:
+    """``counts`` advanced by one step whose per-layer rows per expert are
+    ``rows`` (L, E)."""
+    return {"rows": counts["rows"] + rows,
+            "touched": counts["touched"] + (rows > 0).sum(-1, dtype=jnp.int32),
+            "rows_max": jnp.maximum(counts["rows_max"], rows.max(-1)),
+            "steps": counts["steps"] + 1}
+
+
 def decode_step(params: Dict[str, Any], cfg: ArchConfig,
                 cache: Dict[str, Any], tokens: jax.Array,
                 pos: jax.Array) -> Tuple[jax.Array, Dict[str, Any]]:
@@ -429,16 +472,20 @@ def decode_step(params: Dict[str, Any], cfg: ArchConfig,
             a, kv2 = decode_attention(
                 p["attn"], rms_norm(h, p["attn_norm"]), kv, pos, cfg,
                 window=window)
-            h = h + a
+            h = _residual(cfg, h, a)
             xin = rms_norm(h, p["mlp_norm"])
             if cfg.family == "moe":
-                m, _ = moe_block(p["moe"], xin, cfg, num_groups=1)
-            else:
-                m = _mlp(p["mlp"], xin, cfg)
-            return h + m, kv2
-        h, kv = _scan(
+                m, _, rows = moe_block(p["moe"], xin, cfg, num_groups=1)
+                return _residual(cfg, h, m), (kv2, rows)
+            return _residual(cfg, h, _mlp(p["mlp"], xin, cfg)), kv2
+        h, ys = _scan(
             body, x, (params["layers"], cache["kv"], jnp.asarray(windows)))
-        new_cache: Dict[str, Any] = {"kv": kv}
+        if cfg.family == "moe":
+            kv, rows = ys
+            new_cache: Dict[str, Any] = {
+                "kv": kv, "moe": _count_moe(cache["moe"], rows)}
+        else:
+            new_cache = {"kv": ys}
     elif cfg.family == "ssm":
         def body(h, layer):
             p, c = layer
@@ -551,7 +598,7 @@ def _prime_kv(params, cfg, x, positions, cache, enc_out, use_kernel):
                       window=window, causal=True,
                       use_rope=cfg.family != "encdec",
                       use_kernel=use_kernel)
-        h = h + a
+        h = _residual(cfg, h, a)
         outs = {"k": k, "v": v}
         if cfg.family == "encdec":
             c = attention(p["cross"], rms_norm(h, p["cross_norm"]), cfg,
@@ -567,10 +614,10 @@ def _prime_kv(params, cfg, x, positions, cache, enc_out, use_kernel):
             outs["cv"] = cv2
         xin2 = rms_norm(h, p["mlp_norm"])
         if cfg.family == "moe":
-            m, _ = moe_block(p["moe"], xin2, cfg)
+            m, _, _ = moe_block(p["moe"], xin2, cfg)
         else:
             m = _mlp(p["mlp"], xin2, cfg)
-        h = h + m
+        h = _residual(cfg, h, m)
         return h, outs
 
     if cfg.family == "encdec":

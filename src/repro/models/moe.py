@@ -1,13 +1,21 @@
-"""Mixture-of-Experts layer: top-k routing + capacity dispatch.
+"""Mixture-of-Experts layer: top-k routing, then dropless grouped-matmul
+experts or capacity dispatch.
+
+Dropless (``cfg.moe_dropless``, the published Granite MoE): the token x k
+assignments are sorted by expert and the three expert matmuls run as
+grouped matmuls (``jax.lax.ragged_dot``) over the routed rows only; every
+assignment is computed.  On the TPU, ``ragged_dot`` lowers to its own
+kernel.
 
 TPU-native adaptation of the paper's ``GroupBy`` corner-turn: the token ->
 expert shuffle is *exactly* DALiuGE's static re-grouping (keys known a
-priori: the router's top-k), realised here as a scatter/gather pair that
-GSPMD lowers to all-to-all when experts and tokens live on different mesh
-axes.
+priori: the router's top-k).  Under capacity dispatch it is a
+scatter/gather pair that GSPMD lowers to all-to-all when experts and
+tokens live on different mesh axes.
 
-Dispatch is group-wise (GShard-style): tokens are viewed as (groups, S, d)
-with per-group expert capacity C = S*top_k*capacity_factor/E.  Instead of the
+Capacity dispatch is group-wise (GShard-style): tokens are viewed as
+(groups, S, d) with per-group expert capacity
+C = S*top_k*capacity_factor/E.  Instead of the
 classic one-hot dispatch einsum — O(S*E*C) memory, infeasible at 1M tokens —
 we use scatter-add / gather with computed slot positions, which XLA handles
 as dynamic-update ops and shards cleanly.
@@ -41,15 +49,79 @@ def expert_capacity(cfg: ArchConfig, tokens_per_group: int) -> int:
     return max(8, (c + 7) // 8 * 8)
 
 
+def _route(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig
+           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Router in float32 -> top-k -> gates renormalised to sum 1.
+
+    x: (..., d) -> (gates (..., k), idx (..., k), aux):
+    ``aux`` is the Switch/GShard load-balancing loss
+    E * mean(frac_i * prob_i) over every token."""
+    e, k = cfg.num_experts, cfg.top_k
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), p["router"])
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, idx = jax.lax.top_k(probs, k)
+    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    me = probs.reshape(-1, e).mean(axis=0)
+    ce = jax.nn.one_hot(idx[..., 0], e).reshape(-1, e).mean(axis=0)
+    return gates, idx, e * jnp.sum(me * ce)
+
+
+def _expert_ffn(cfg: ArchConfig, h1: jax.Array, h3) -> jax.Array:
+    """The gated (or plain) activation between the first and last expert
+    matmuls; ``h3`` is the gate branch (None for ungated activations)."""
+    if cfg.activation in ("swiglu", "geglu"):
+        gate = jax.nn.silu if cfg.activation == "swiglu" else jax.nn.gelu
+        return gate(h1) * h3
+    return activation_fn(cfg.activation)(h1)
+
+
+def _dropless(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Every token's k assignments, grouped by expert, through one grouped
+    matmul per expert weight: nothing is dropped and no capacity is
+    computed.  x: (T, d) -> (y (T, d), aux, rows per expert (E,))."""
+    t, d = x.shape
+    e, k = cfg.num_experts, cfg.top_k
+    prof = sctx.current()
+    if prof is not None and prof.mesh is not None and prof.expert_axis:
+        raise NotImplementedError(
+            "dropless MoE under a mesh that shards experts "
+            f"(profile {prof.name!r}, axis {prof.expert_axis!r}) is not "
+            "supported: expert-parallel dropless dispatch is not written")
+    with jax.named_scope("moe.route"):
+        gates, idx, aux = _route(p, x, cfg)
+        flat = idx.reshape(t * k)
+        order = jnp.argsort(flat, stable=True)       # assignments by expert
+        rows = jnp.bincount(flat, length=e).astype(jnp.int32)
+    with jax.named_scope("moe.gmm"):
+        xs = x[order // k]                           # (T*k, d), sorted
+        h1 = jax.lax.ragged_dot(xs, p["w1"], rows)
+        h3 = (jax.lax.ragged_dot(xs, p["w3"], rows)
+              if "w3" in p else None)
+        out = jax.lax.ragged_dot(_expert_ffn(cfg, h1, h3).astype(x.dtype),
+                                 p["w2"], rows)      # (T*k, d)
+    with jax.named_scope("moe.combine"):
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=order.dtype))
+        y = jnp.einsum("tkd,tk->td",
+                       out[inv].reshape(t, k, d).astype(jnp.float32), gates)
+    return y.astype(x.dtype), aux, rows
+
+
 def moe_block(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig,
               num_groups: Optional[int] = None
-              ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (y, aux_loss).
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B, S, d) -> (y, aux_loss, rows routed to each expert (E,)).
 
-    ``num_groups``: dispatch groups (defaults to B).  Tokens within a group
-    share one capacity budget; groups shard over the data axes.
+    With ``cfg.moe_dropless`` every assignment is computed (``_dropless``).
+    Otherwise dispatch is by capacity: ``num_groups`` dispatch groups
+    (defaults to B); tokens within a group share one capacity budget, and
+    groups shard over the data axes.
     """
     b, s, d = x.shape
+    if cfg.moe_dropless:
+        y, aux, rows = _dropless(p, x.reshape(b * s, d), cfg)
+        return y.reshape(b, s, d), aux, rows
     e, k = cfg.num_experts, cfg.top_k
     g = num_groups if num_groups else b
     tokens = b * s
@@ -59,16 +131,8 @@ def moe_block(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig,
     cap = expert_capacity(cfg, sg)
 
     # --- routing ------------------------------------------------------------
-    logits = jnp.einsum("gsd,de->gse", xg.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k)                 # (g, sg, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-
-    # load-balancing aux loss (Switch/GShard): E * mean(frac_i * prob_i)
-    me = probs.mean(axis=(0, 1))                          # (e,)
-    one_hot_top1 = jax.nn.one_hot(idx[..., 0], e)
-    ce = one_hot_top1.mean(axis=(0, 1))
-    aux = e * jnp.sum(me * ce)
+    gates, idx, aux = _route(p, xg, cfg)                 # (g, sg, k)
+    rows = jnp.bincount(idx.reshape(-1), length=e).astype(jnp.int32)
 
     # --- slot positions within each expert's capacity ----------------------------
     # flatten the k assignment slots; earlier slots win capacity
@@ -92,14 +156,10 @@ def moe_block(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig,
     buf = sctx.constrain(buf, "moe_buffer")
 
     # --- expert FFN (E stacked experts; f-dim is TP-sharded) ----------------------
-    act = activation_fn(cfg.activation)
     h = jnp.einsum("gecd,edf->gecf", buf, p["w1"])
-    if cfg.activation in ("swiglu", "geglu"):
-        gate = jax.nn.silu if cfg.activation == "swiglu" else jax.nn.gelu
-        hg = jnp.einsum("gecd,edf->gecf", buf, p["w3"])
-        h = gate(h) * hg
-    else:
-        h = act(h)
+    hg = (jnp.einsum("gecd,edf->gecf", buf, p["w3"])
+          if cfg.activation in ("swiglu", "geglu") else None)
+    h = _expert_ffn(cfg, h, hg)
     out_buf = jnp.einsum("gecf,efd->gecd", h, p["w2"])
     out_buf = sctx.constrain(out_buf, "moe_buffer")
 
@@ -109,4 +169,4 @@ def moe_block(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig,
     gathered = gathered.reshape(g, sg, k, d)
     y = jnp.einsum("gskd,gsk->gsd", gathered.astype(jnp.float32),
                    gates).astype(x.dtype)
-    return y.reshape(b, s, d), aux
+    return y.reshape(b, s, d), aux, rows

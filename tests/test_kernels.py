@@ -87,6 +87,36 @@ class TestFlashAttention:
             np.asarray(f(q, k, v)),
             np.asarray(ref.mha_reference(q, k, v)), atol=2e-5, rtol=2e-5)
 
+    def test_caller_scale(self):
+        """The score scale comes from the caller: Granite's 1/64 (in place
+        of 1/sqrt(64)) gives the oracle's output at that scale."""
+        q = rnd(15, (1, 4, 64, 64), jnp.float32, 4.0)
+        k = rnd(16, (1, 2, 64, 64), jnp.float32, 4.0)
+        v = rnd(17, (1, 2, 64, 64), jnp.float32)
+        out = flash_attention_bhsd(q, k, v, scale=1 / 64, block_q=32,
+                                   block_k=32, interpret=True)
+        want = ref.mha_reference(q, k, v, scale=1 / 64)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+        default = ref.mha_reference(q, k, v)
+        assert np.abs(np.asarray(want) - np.asarray(default)).max() > 1e-2
+
+    def test_model_attention_follows_the_multiplier(self):
+        """``attention(use_kernel=True)`` scales the scores as the jnp path
+        does, by the configuration's ``attention_multiplier``."""
+        from repro.configs import get_smoke_config
+        from repro.models.attention import attention, init_attention
+        from repro.models.common import KeyGen
+        cfg = get_smoke_config("granite_moe_3b_a800m")
+        assert cfg.attention_multiplier == 1 / 64
+        p = init_attention(KeyGen(jax.random.PRNGKey(18)), cfg, jnp.float32)
+        x = rnd(19, (2, 32, cfg.d_model), jnp.float32, 4.0)
+        pos = jnp.broadcast_to(jnp.arange(32), (2, 32))
+        outs = [attention(p, x, cfg, positions=pos, use_kernel=kern)
+                for kern in (False, True)]
+        np.testing.assert_allclose(np.asarray(outs[1]), np.asarray(outs[0]),
+                                   atol=1e-4, rtol=1e-4)
+
 
 class TestSSDScan:
     @pytest.mark.parametrize("b,h,s,p,n,chunk", [

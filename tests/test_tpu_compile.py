@@ -1,18 +1,20 @@
-"""Chip compiles of the Pallas kernels and of a serving step, at the widths
-the models run them at.
+"""Chip compiles of the Pallas kernels and of the serving steps, at the
+widths the models run them at.
 
 Each test lowers a program for one chip of a described (not attached) TPU
 v5e topology.  A kernel, lowered with ``interpret=False``, must be accepted
-by Mosaic: the compiled program carries a ``tpu_custom_call``.  The decode
-step must keep its cache's layout: the compiled program is read for copies
-and temporaries.  Nothing runs; this is what the chip's compiler would
-refuse or re-lay out, caught without the chip.
+by Mosaic: the compiled program carries a ``tpu_custom_call``.  The serving
+steps must keep their cache's layout and their programs: the compiled
+programs are read for copies, temporaries and instruction counts.  Nothing
+runs; this is what the chip's compiler would refuse or re-lay out, caught
+without the chip.
 
 The topology is described inside a module fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.  The persistent compilation cache is off around these compiles,
 since an entry compiled for a described chip cannot be read back here.
 """
+import functools
 import os
 import re
 
@@ -24,7 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import flash_attention_bhsd, ssd_scan_bhsd
 from repro.models import model as M
-from repro.train.steps import make_decode_step
+from repro.train.steps import make_decode_step, make_prefill_step
 
 
 @pytest.fixture(scope="module")
@@ -112,3 +114,63 @@ def test_mamba2_decode_step_keeps_the_state_layout(one_chip):
     assert not copies, copies
     one_layer = mb * h * p * n * 4
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer
+
+
+def _ops(compiled):
+    """The number of HLO instructions of a compiled program."""
+    return sum(1 for line in compiled.as_text().splitlines()
+               if re.match(r"\s*(ROOT )?%\S+ = ", line))
+
+
+def test_mamba2_steps_keep_their_programs(one_chip):
+    """Granite's multipliers are branched on in Python at their neutral
+    values, so mamba2-1.3b's decode and prefill programs (the benchmarked
+    cell's, at mb 16 and 512-token prompts, the shared ``embed_tokens``
+    and ``logits_fn`` included) compile to what they did before the
+    multipliers existed: the same temporaries and instruction count."""
+    cfg = get_config("mamba2_1_3b")
+    mb, prompt = 16, 512
+
+    def spec(path, a):
+        state = "state" in jax.tree_util.keystr(path)
+        return _spec(a.shape, jnp.float32 if state else a.dtype, one_chip)
+    params = jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda k: M.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree_util.tree_map_with_path(
+        spec, jax.eval_shape(lambda: M.init_cache(cfg, mb, 1)))
+    decode = jax.jit(make_decode_step(cfg)).lower(
+        params, cache, _spec((mb, 1), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip)).compile()
+    prefill = jax.jit(make_prefill_step(cfg)).lower(
+        params, {"tokens": _spec((mb, prompt), jnp.int32, one_chip)}
+    ).compile()
+    assert (decode.memory_analysis().temp_size_in_bytes, _ops(decode)) == (
+        225_792, 485)
+    assert (prefill.memory_analysis().temp_size_in_bytes, _ops(prefill)) == (
+        582_951_936, 759)
+
+
+def test_granite_dropless_decode_is_a_grouped_matmul(one_chip):
+    """granite-3.0-3b-a800m's decode step at the served microbatch runs its
+    experts as grouped matmuls over the 64 routed rows (the TPU's
+    ``ragged-dot`` kernel, three per layer), not as a matmul over all 40
+    experts' slots."""
+    cfg = get_config("granite_moe_3b_a800m")
+    mb, rows = 8, 8 * cfg.top_k
+    shape = functools.partial(_spec, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda k: M.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    cache = jax.tree.map(lambda a: shape(a.shape, a.dtype),
+                         jax.eval_shape(lambda: M.init_cache(cfg, mb, 576)))
+    text = jax.jit(make_decode_step(cfg)).lower(
+        params, cache, shape((mb, 1), jnp.int32),
+        shape((), jnp.int32)).compile().as_text()
+    gmm = re.findall(r"%ragged-dot-none\S* = bf16\[(\d+),(\d+)\]", text)
+    assert sorted(gmm) == sorted([(str(rows), str(cfg.d_ff))] * 2
+                                 + [(str(rows), str(cfg.d_model))])
+    # the capacity route's buffer of 8 slots per expert is gone
+    assert f"bf16[1,{cfg.num_experts},8," not in text
