@@ -451,6 +451,24 @@ def _count_moe(counts: Dict[str, jax.Array],
             "steps": counts["steps"] + 1}
 
 
+def _in_place_experts(cfg: ArchConfig, layers: Dict[str, Any]
+                      ) -> Tuple[Dict[str, Any], Dict[str, jax.Array]]:
+    """``layers`` without the dropless MoE's expert stacks, and the stacks.
+
+    The inference scans close over the whole (L, E, ...) stacks and give
+    each layer its index, so the TPU's grouped matmuls read the layer's
+    experts in place (``moe._in_place``): a slice scanned out of a stack
+    would be copied whole before the kernel reads it.  The training
+    forward keeps scanning slices, since a gradient through the whole
+    stack would build a whole-stack cotangent in every layer.  Other
+    configs: (layers, {})."""
+    if not (cfg.family == "moe" and cfg.moe_dropless):
+        return layers, {}
+    moe = dict(layers["moe"])
+    stacks = {n: moe.pop(n) for n in ("w1", "w2", "w3") if n in moe}
+    return {**layers, "moe": moe}, stacks
+
+
 def decode_step(params: Dict[str, Any], cfg: ArchConfig,
                 cache: Dict[str, Any], tokens: jax.Array,
                 pos: jax.Array) -> Tuple[jax.Array, Dict[str, Any]]:
@@ -466,20 +484,23 @@ def decode_step(params: Dict[str, Any], cfg: ArchConfig,
         windows = layer_windows(cfg, 0)
         if windows is None:
             windows = np.zeros((cfg.num_layers,), np.int32)
+        layers, experts = _in_place_experts(cfg, params["layers"])
+        at = jnp.arange(cfg.num_layers) if experts else None
 
         def body(h, layer):
-            p, kv, window = layer
+            p, kv, window, i = layer
             a, kv2 = decode_attention(
                 p["attn"], rms_norm(h, p["attn_norm"]), kv, pos, cfg,
                 window=window)
             h = _residual(cfg, h, a)
             xin = rms_norm(h, p["mlp_norm"])
             if cfg.family == "moe":
-                m, _, rows = moe_block(p["moe"], xin, cfg, num_groups=1)
+                m, _, rows = moe_block({**p["moe"], **experts}, xin, cfg,
+                                       num_groups=1, layer=i)
                 return _residual(cfg, h, m), (kv2, rows)
             return _residual(cfg, h, _mlp(p["mlp"], xin, cfg)), kv2
         h, ys = _scan(
-            body, x, (params["layers"], cache["kv"], jnp.asarray(windows)))
+            body, x, (layers, cache["kv"], jnp.asarray(windows), at))
         if cfg.family == "moe":
             kv, rows = ys
             new_cache: Dict[str, Any] = {
@@ -583,13 +604,15 @@ def _prime_kv(params, cfg, x, positions, cache, enc_out, use_kernel):
     windows = layer_windows(cfg, x.shape[1])
     if windows is None:
         windows = np.zeros((cfg.num_layers,), np.int32)
+    layers, experts = _in_place_experts(cfg, params["layers"])
+    at = jnp.arange(cfg.num_layers) if experts else None
 
     def body(carry, layer):
         h = carry
         if cfg.family == "encdec":
-            p, window, ck, cv = layer
+            p, window, i, ck, cv = layer
         else:
-            p, window = layer
+            p, window, i = layer
         xin = rms_norm(h, p["attn_norm"])
         _, k, v = _project_qkv(p["attn"], xin, xin, cfg, positions,
                                positions,
@@ -614,17 +637,17 @@ def _prime_kv(params, cfg, x, positions, cache, enc_out, use_kernel):
             outs["cv"] = cv2
         xin2 = rms_norm(h, p["mlp_norm"])
         if cfg.family == "moe":
-            m, _, _ = moe_block(p["moe"], xin2, cfg)
+            m, _, _ = moe_block({**p["moe"], **experts}, xin2, cfg, layer=i)
         else:
             m = _mlp(p["mlp"], xin2, cfg)
         h = _residual(cfg, h, m)
         return h, outs
 
     if cfg.family == "encdec":
-        xs = (params["layers"], jnp.asarray(windows),
+        xs = (layers, jnp.asarray(windows), at,
               cache["cross_k"], cache["cross_v"])
     else:
-        xs = (params["layers"], jnp.asarray(windows))
+        xs = (layers, jnp.asarray(windows), at)
     h, outs = _scan(body, x, xs)
     kv = {"k": outs["k"].astype(cache["kv"]["k"].dtype),
           "v": outs["v"].astype(cache["kv"]["v"].dtype)}

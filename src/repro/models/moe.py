@@ -75,11 +75,19 @@ def _expert_ffn(cfg: ArchConfig, h1: jax.Array, h3) -> jax.Array:
     return activation_fn(cfg.activation)(h1)
 
 
-def _dropless(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig
+def _dropless(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig,
+              layer: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Every token's k assignments, grouped by expert, through one grouped
     matmul per expert weight: nothing is dropped and no capacity is
-    computed.  x: (T, d) -> (y (T, d), aux, rows per expert (E,))."""
+    computed.  x: (T, d) -> (y (T, d), aux, rows per expert (E,)).
+
+    With ``layer`` (the inference scans), ``p`` holds the layer's router
+    and the whole (L, E, ...) expert stacks.  On the TPU the grouped
+    matmuls read layer ``layer``'s experts where they lie (``_in_place``).
+    Elsewhere ``ragged_dot`` is lowered densely over every group, which
+    over L*E groups would multiply by every layer's experts and round
+    unlike the layer's own E, so the layer's experts are sliced out."""
     t, d = x.shape
     e, k = cfg.num_experts, cfg.top_k
     prof = sctx.current()
@@ -95,11 +103,16 @@ def _dropless(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig
         rows = jnp.bincount(flat, length=e).astype(jnp.int32)
     with jax.named_scope("moe.gmm"):
         xs = x[order // k]                           # (T*k, d), sorted
-        h1 = jax.lax.ragged_dot(xs, p["w1"], rows)
-        h3 = (jax.lax.ragged_dot(xs, p["w3"], rows)
-              if "w3" in p else None)
-        out = jax.lax.ragged_dot(_expert_ffn(cfg, h1, h3).astype(x.dtype),
-                                 p["w2"], rows)      # (T*k, d)
+        w = {n: p[n] for n in ("w1", "w2", "w3") if n in p}
+        if layer is None:
+            out = _gmm(cfg, xs, w, rows)
+        else:
+            out = jax.lax.platform_dependent(
+                xs, w, rows, layer,
+                tpu=lambda xs, w, rows, i: _gmm(
+                    cfg, xs, *_in_place(w, rows, i)),
+                default=lambda xs, w, rows, i: _gmm(
+                    cfg, xs, {n: a[i] for n, a in w.items()}, rows))
     with jax.named_scope("moe.combine"):
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(t * k, dtype=order.dtype))
@@ -108,19 +121,49 @@ def _dropless(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig
     return y.astype(x.dtype), aux, rows
 
 
+def _gmm(cfg: ArchConfig, xs: jax.Array, w: Dict[str, jax.Array],
+         sizes: jax.Array) -> jax.Array:
+    """The expert FFN of the sorted rows ``xs`` (T*k, d) as three grouped
+    matmuls over the experts of ``w``, ``sizes`` rows each."""
+    h1 = jax.lax.ragged_dot(xs, w["w1"], sizes)
+    h3 = jax.lax.ragged_dot(xs, w["w3"], sizes) if "w3" in w else None
+    return jax.lax.ragged_dot(_expert_ffn(cfg, h1, h3).astype(xs.dtype),
+                              w["w2"], sizes)
+
+
+def _in_place(w: Dict[str, jax.Array], rows: jax.Array, layer: jax.Array
+              ) -> Tuple[Dict[str, jax.Array], jax.Array]:
+    """The grouped matmuls' operands for layer ``layer``'s experts read in
+    place: each (L, E, ...) stack viewed as L*E groups (a bitcast), and
+    group sizes that are ``rows`` at the layer's E groups and 0 elsewhere.
+
+    ``ragged_dot`` is a custom call on the TPU that takes each weight as a
+    whole buffer, so a layer's (E, ...) slice fed to it is copied first;
+    the whole stack is not.  Its kernel visits only groups with rows, so
+    it streams from HBM the experts this layer's rows reached and nothing
+    of the other layers'."""
+    n = next(iter(w.values())).shape[0] * rows.shape[0]
+    flat = {name: a.reshape(n, *a.shape[2:]) for name, a in w.items()}
+    sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros((n,), rows.dtype), rows, (layer * rows.shape[0],))
+    return flat, sizes
+
+
 def moe_block(p: Dict[str, jax.Array], x: jax.Array, cfg: ArchConfig,
-              num_groups: Optional[int] = None
+              num_groups: Optional[int] = None,
+              layer: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x: (B, S, d) -> (y, aux_loss, rows routed to each expert (E,)).
 
-    With ``cfg.moe_dropless`` every assignment is computed (``_dropless``).
-    Otherwise dispatch is by capacity: ``num_groups`` dispatch groups
-    (defaults to B); tokens within a group share one capacity budget, and
-    groups shard over the data axes.
+    With ``cfg.moe_dropless`` every assignment is computed (``_dropless``;
+    ``layer`` given, ``p``'s expert weights are the whole stacks and layer
+    ``layer``'s are read in place).  Otherwise dispatch is by capacity:
+    ``num_groups`` dispatch groups (defaults to B); tokens within a group
+    share one capacity budget, and groups shard over the data axes.
     """
     b, s, d = x.shape
     if cfg.moe_dropless:
-        y, aux, rows = _dropless(p, x.reshape(b * s, d), cfg)
+        y, aux, rows = _dropless(p, x.reshape(b * s, d), cfg, layer)
         return y.reshape(b, s, d), aux, rows
     e, k = cfg.num_experts, cfg.top_k
     g = num_groups if num_groups else b

@@ -2,6 +2,8 @@
 
 * the dropless route equals a per-token loop over the chosen experts, at a
   size where capacity dispatch drops tokens (and does drop them);
+* its in-place form, which reads one layer's experts in the whole stacks,
+  equals it exactly, and the training forward keeps slicing the stacks;
 * a granite smoke configuration with all four multipliers serves from a
   primed cache the logits of one full forward;
 * the routing counters a decode cache carries match the routing
@@ -19,7 +21,7 @@ from jax.sharding import Mesh
 from repro.configs import get_smoke_config
 from repro.models import model as M
 from repro.models.common import ArchConfig, KeyGen
-from repro.models.moe import init_moe, moe_block
+from repro.models.moe import _gmm, _in_place, init_moe, moe_block
 from repro.sharding.ctx import ShardProfile, use_profile
 
 KEY = jax.random.PRNGKey(0)
@@ -70,6 +72,89 @@ def test_dropless_equals_a_per_token_loop_where_capacity_drops():
     assert (wrong > 1e-3).sum() > 0
 
 
+def _stacked_moe(cfg, routing):
+    """Three layers' MoE params, their routers set up for ``routing``, and
+    inputs for them."""
+    kg = KeyGen(KEY)
+    layers = [init_moe(kg, cfg, jnp.float32) for _ in range(3)]
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
+    if routing != "spread":
+        x = jnp.abs(x)          # positive inputs: router columns decide
+        for p in layers:
+            r = p["router"] * 0.1
+            if routing == "idle_experts":
+                r = r.at[:, [2, 5]].set(-1.0)      # never in a top-k
+            else:                                  # "one_expert"
+                r = r.at[:, 3].set(1.0)            # every row's top-1
+            p["router"] = r
+    return layers, x
+
+
+@pytest.mark.parametrize("routing, top_k", [("spread", 2),
+                                            ("idle_experts", 2),
+                                            ("one_expert", 1)])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_in_place_experts_equal_the_sliced_layer(layer, routing, top_k):
+    """Layer ``layer``'s experts read in place in the (3, E, ...) stacks
+    give exactly the y, aux and rows of the layer's own (E, ...) slice;
+    and the TPU's operands, the stacks as 3 x E groups with rows only in
+    the layer's, give exactly the slice's grouped matmuls."""
+    cfg = moe_cfg(moe_dropless=True, top_k=top_k)
+    layers, x = _stacked_moe(cfg, routing)
+    stacks = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+    sliced = jax.jit(lambda p, x: moe_block(p, x, cfg))(layers[layer], x)
+    in_place = jax.jit(lambda p, x, i: moe_block(p, x, cfg, layer=i))(
+        {**stacks, "router": layers[layer]["router"]}, x, jnp.int32(layer))
+    for want, got in zip(sliced, in_place):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    rows = np.asarray(sliced[2])
+    if routing == "idle_experts":
+        assert rows[2] == rows[5] == 0 and (rows > 0).sum() > 2
+    if routing == "one_expert":
+        assert rows[3] == x.shape[0] * x.shape[1] == rows.sum()
+
+    xs = jnp.repeat(x.reshape(-1, cfg.d_model), top_k, axis=0)
+    w = {n: stacks[n] for n in ("w1", "w2", "w3")}
+    want = _gmm(cfg, xs, jax.tree.map(lambda a: a[layer], w), sliced[2])
+    got = jax.jit(lambda w, r, i: _gmm(cfg, xs, *_in_place(w, r, i)))(
+        w, sliced[2], jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _groups(jaxpr):
+    """The group count of every ``ragged_dot_general`` in ``jaxpr`` and the
+    jaxprs nested in it (the length of its group sizes)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "ragged_dot_general":
+            yield eqn.invars[2].aval.shape[0]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _groups(sub)
+
+
+def test_training_forward_keeps_slicing_the_experts():
+    """The inference scans read the experts in place over L*E groups (on
+    the TPU; the jaxpr holds both platforms' routes); the training forward
+    and its gradient run every grouped matmul over one layer's E, so no
+    cotangent is as large as a whole stack."""
+    cfg = dataclasses.replace(granite_smoke(), num_layers=3)
+    params = M.init_params(cfg, KEY)
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, 9), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+    e, flat = cfg.num_experts, cfg.num_layers * cfg.num_experts
+
+    def groups(fn, *args):
+        return set(_groups(jax.make_jaxpr(fn)(*args).jaxpr))
+
+    grad = jax.grad(lambda p: M.forward_train(p, cfg, batch)[0])
+    assert groups(grad, params) == {e}
+    cache = M.init_cache(cfg, 2, 8)
+    assert flat in groups(lambda p: M.decode_step(
+        p, cfg, cache, toks[:, :1], jnp.int32(0)), params)
+    assert flat in groups(lambda p: M.prefill(p, cfg, {"tokens": toks}),
+                          params)
+
+
 def test_dropless_refuses_an_expert_sharded_mesh():
     cfg = moe_cfg(moe_dropless=True)
     p = init_moe(KeyGen(KEY), cfg, jnp.float32)
@@ -93,17 +178,23 @@ def granite_smoke():
 
 
 def test_granite_cache_decode_matches_one_forward():
-    """Prefill of S tokens, then one decode step from the primed cache,
-    gives the logits of one forward over S + 1 tokens, to float32
-    rounding; and each multiplier changes them."""
+    """Prefill of S tokens, then one decode step from the primed cache
+    (both by the inference route, which indexes the whole expert stacks
+    by layer), gives the logits of one forward over S + 1 tokens by the
+    training route (slices of the stacks scanned), to float32 rounding,
+    as does a prefill of all S + 1; and each multiplier changes them."""
     cfg = granite_smoke()
     params = M.init_params(cfg, KEY)
     B, S = 2, 12
     toks = jax.random.randint(jax.random.PRNGKey(3), (B, S + 1), 0,
                               cfg.vocab_size)
+    positions = jnp.broadcast_to(jnp.arange(S + 1), toks.shape)
 
     def served(cfg):
-        full, _ = jax.jit(lambda p, t: M.prefill(p, cfg, {"tokens": t}))(
+        h, _ = jax.jit(lambda p, t: M.backbone(
+            p, cfg, M.embed_tokens(p, cfg, t), positions))(params, toks)
+        full = M.logits_fn(params, cfg, h[:, -1:])
+        whole, _ = jax.jit(lambda p, t: M.prefill(p, cfg, {"tokens": t}))(
             params, toks)
         _, primed = jax.jit(lambda p, t: M.prefill(p, cfg, {"tokens": t}))(
             params, toks[:, :S])
@@ -113,16 +204,18 @@ def test_granite_cache_decode_matches_one_forward():
                                      zip(d.shape, s.shape)]), grown, primed)
         step, _ = jax.jit(lambda p, c, t: M.decode_step(
             p, cfg, c, t, jnp.int32(S)))(params, cache, toks[:, S:])
-        return np.asarray(full[:, 0]), np.asarray(step[:, 0])
+        return (np.asarray(full[:, 0]), np.asarray(step[:, 0]),
+                np.asarray(whole[:, 0]))
 
-    full, step = served(cfg)
+    full, step, whole = served(cfg)
     scale = np.abs(full).max()
     np.testing.assert_allclose(step, full, rtol=0, atol=1e-5 * scale)
+    np.testing.assert_allclose(whole, full, rtol=0, atol=1e-5 * scale)
     for name, neutral in [("embedding_multiplier", 1.0),
                           ("attention_multiplier", None),
                           ("residual_multiplier", 1.0),
                           ("logits_scaling", 1.0)]:
-        other, _ = served(dataclasses.replace(cfg, **{name: neutral}))
+        other, _, _ = served(dataclasses.replace(cfg, **{name: neutral}))
         assert np.abs(other - full).max() > 1e-3 * scale, name
 
 

@@ -152,25 +152,78 @@ def test_mamba2_steps_keep_their_programs(one_chip):
         582_951_936, 759)
 
 
-def test_granite_dropless_decode_is_a_grouped_matmul(one_chip):
-    """granite-3.0-3b-a800m's decode step at the served microbatch runs its
-    experts as grouped matmuls over the 64 routed rows (the TPU's
-    ``ragged-dot`` kernel, three per layer), not as a matmul over all 40
-    experts' slots."""
+def _granite_step(one_chip, make_step, *args):
+    """granite-3.0-3b-a800m's ``make_step`` compiled at full width, with
+    ``args`` built from its shapes."""
     cfg = get_config("granite_moe_3b_a800m")
-    mb, rows = 8, 8 * cfg.top_k
     shape = functools.partial(_spec, sharding=one_chip)
     params = jax.tree.map(
         lambda a: shape(a.shape, a.dtype),
         jax.eval_shape(lambda k: M.init_params(cfg, k),
                        jax.random.PRNGKey(0)))
-    cache = jax.tree.map(lambda a: shape(a.shape, a.dtype),
-                         jax.eval_shape(lambda: M.init_cache(cfg, mb, 576)))
-    text = jax.jit(make_decode_step(cfg)).lower(
-        params, cache, shape((mb, 1), jnp.int32),
-        shape((), jnp.int32)).compile().as_text()
+    return jax.jit(make_step(cfg)).lower(
+        params, *jax.tree.map(lambda a: shape(a.shape, a.dtype), args)
+    ).compile()
+
+
+GRANITE_MB, GRANITE_CACHE, GRANITE_PROMPT = 8, 576, 512
+
+
+@pytest.fixture(scope="module")
+def granite_decode(one_chip):
+    """The served decode step: mb 8 over a 576-token cache."""
+    cfg = get_config("granite_moe_3b_a800m")
+    cache = jax.eval_shape(
+        lambda: M.init_cache(cfg, GRANITE_MB, GRANITE_CACHE))
+    return _granite_step(one_chip, make_decode_step, cache,
+                         jax.ShapeDtypeStruct((GRANITE_MB, 1), jnp.int32),
+                         jax.ShapeDtypeStruct((), jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def granite_prefill(one_chip):
+    """The served prefill step: mb 8 prompts of 512 tokens."""
+    return _granite_step(one_chip, make_prefill_step, {
+        "tokens": jax.ShapeDtypeStruct((GRANITE_MB, GRANITE_PROMPT),
+                                       jnp.int32)})
+
+
+def test_granite_dropless_decode_is_a_grouped_matmul(granite_decode):
+    """granite-3.0-3b-a800m's decode step at the served microbatch runs its
+    experts as grouped matmuls over the 64 routed rows (the TPU's
+    ``ragged-dot`` kernel, three per layer), not as a matmul over all 40
+    experts' slots."""
+    cfg = get_config("granite_moe_3b_a800m")
+    rows = GRANITE_MB * cfg.top_k
+    text = granite_decode.as_text()
     gmm = re.findall(r"%ragged-dot-none\S* = bf16\[(\d+),(\d+)\]", text)
     assert sorted(gmm) == sorted([(str(rows), str(cfg.d_ff))] * 2
                                  + [(str(rows), str(cfg.d_model))])
     # the capacity route's buffer of 8 slots per expert is gone
     assert f"bf16[1,{cfg.num_experts},8," not in text
+
+
+@pytest.mark.parametrize("step", ["granite_decode", "granite_prefill"])
+def test_granite_grouped_matmuls_read_the_experts_in_place(step, request):
+    """Decode and prefill feed each grouped matmul the whole 32-layer
+    expert stack, viewed as 32 x 40 groups (a bitcast of the parameter),
+    so no instruction copies a layer's (40, ...) stack out of it; the
+    decode step's temporaries stay under one layer's three stacks."""
+    cfg = get_config("granite_moe_3b_a800m")
+    compiled = request.getfixturevalue(step)
+    text = compiled.as_text()
+    e, d, f = cfg.num_experts, cfg.d_model, cfg.d_ff
+    staged = re.findall(rf"= bf16\[{e},({d},{f}|{f},{d})\]", text)
+    assert not staged, staged
+    types = dict(re.findall(r"^\s*(?:ROOT )?(%\S+) = (\S+)", text, re.M))
+    gmm = re.findall(r"%ragged-dot-none\S* = .*? custom-call\(([^)]*)\)",
+                     text)
+    assert len(gmm) == 3
+    flat = f"bf16[{cfg.num_layers * e},"
+    for operands in gmm:
+        weights = [types[o.split("*/")[-1].strip()] for o in
+                   operands.split(",")]
+        assert any(t.startswith(flat) for t in weights), weights
+    if step == "granite_decode":
+        one_layer = 3 * e * d * f * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < one_layer
