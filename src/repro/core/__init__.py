@@ -13,8 +13,8 @@ from .events import Event, EventBus, RecordingListener
 from .exec_compiled import ExecHooks, execute_frontier
 from .fault import FaultManager, StragglerWatcher, elastic_remap, with_retries
 from .resilience import (CompiledFaultManager, FailureScript,
-                         ResilienceConfig, ResilienceStats, ResilientRunner,
-                         RetryPolicy, StragglerPolicy, execute_resilient)
+                         ResilienceConfig, ResilienceStats, RetryPolicy,
+                         StragglerPolicy, execute_resilient)
 from .graph_io import iter_pgt, load_lgt, load_pgt, save_lgt, save_pgt
 from .lifecycle import DataLifecycleManager
 from .logical import (GraphValidationError, LogicalGraph,
@@ -52,7 +52,7 @@ __all__ = [
     "NullPayload", "PartitionResult", "Payload", "PayloadError",
     "PayloadPlane", "PhysicalGraphTemplate", "Pipeline",
     "ProcExecutor", "ProcNodeDropManager", "RecordingListener",
-    "ResilienceConfig", "ResilienceStats", "ResilientRunner", "RetryPolicy",
+    "ResilienceConfig", "ResilienceStats", "RetryPolicy",
     "Session", "SessionState", "SessionTicket", "Span", "StragglerPolicy",
     "StragglerWatcher", "StreamAbort", "StreamConfig", "StreamTable",
     "TelemetryConfig", "TemplateCache", "Timeline", "WorkerLost",

@@ -107,10 +107,6 @@ class ExecHooks:
       wave, when all drop state is consistent (everything terminal or
       INIT, no in-flight work).  May raise to abort the run; the state
       array stays resumable.
-    * ``python_runner(ctx, ids)`` — replaces the sequential registry-app
-      loop for the wave's Python apps (``ctx`` is the ``_Dispatch``;
-      ``ids`` are node-sorted and may span nodes).  Must leave every id
-      terminal, or raise ``_WaveTimeout`` past ``ctx.deadline``.
     * ``on_stream_chunk(session, src_uid, dst_uid, seq)`` — one call per
       chunk *consumed* by a streaming consumer (compiled lane) or per
       chunk *delivered* by ``DataDrop.write`` (object engine).  Runs on
@@ -121,13 +117,11 @@ class ExecHooks:
       never queues them).
     """
 
-    __slots__ = ("on_wave", "python_runner", "on_stream_chunk",
-                 "on_backpressure")
+    __slots__ = ("on_wave", "on_stream_chunk", "on_backpressure")
 
-    def __init__(self, on_wave=None, python_runner=None,
-                 on_stream_chunk=None, on_backpressure=None) -> None:
+    def __init__(self, on_wave=None, on_stream_chunk=None,
+                 on_backpressure=None) -> None:
         self.on_wave = on_wave
-        self.python_runner = python_runner
         self.on_stream_chunk = on_stream_chunk
         self.on_backpressure = on_backpressure
 
@@ -138,11 +132,45 @@ _gather = csr_gather
 _gather_with_counts = csr_gather_with_counts
 
 
-def node_batches(pgt: CompiledPGT, ids: np.ndarray) -> List[np.ndarray]:
-    """Split drop ids into per-placement-node batches (stable order).
+class DispatchPolicy:
+    """How ``_Dispatch`` runs and lands registry apps: ``retry`` (a
+    ``RetryPolicy``) re-runs failed apps, ``speculation`` (a
+    ``resilience._Speculation``) duplicates stragglers, ``stats`` (a
+    ``ResilienceStats``) counts both.  The lock and the epoch fence every
+    landing: first writer wins, and :meth:`invalidate` voids work started
+    before a recovery.  The default (one attempt, no speculation) is the
+    plain path; ``execute_resilient`` keeps one policy across resumes."""
 
-    Shared by the default threaded wave dispatch below and the
-    resilience runner's speculative dispatch (same argsort-and-split)."""
+    def __init__(self, retry: Any = None, speculation: Any = None,
+                 stats: Any = None) -> None:
+        self.max_attempts = retry.max_attempts if retry is not None else 1
+        self.backoff = retry.backoff if retry is not None else 0.0
+        self.speculation = speculation
+        self.stats = stats
+        self.lock = threading.Lock()
+        self.epoch = 0
+
+    def invalidate(self) -> None:
+        """Fence all in-flight work: a recovery is about to reset state
+        rows to INIT, and a leftover attempt landing a stale result would
+        hide its drop from the resumed scheduler's frontier."""
+        with self.lock:
+            self.epoch += 1
+
+    def count(self, s: CompiledSession, name: str, n: int = 1) -> None:
+        """Add ``n`` to resilience counter ``name`` on the stats, the
+        session and its metrics (the caller holds ``lock``)."""
+        if self.stats is not None:
+            setattr(self.stats, name, getattr(self.stats, name) + n)
+        if name != "speculative_losses":
+            setattr(s, name, getattr(s, name) + n)
+            if s.metrics is not None:
+                s.metrics.counter("resilience." + name).inc(n)
+
+
+def node_batches(pgt: CompiledPGT, ids: np.ndarray) -> List[np.ndarray]:
+    """Split drop ids into per-placement-node batches (stable order) —
+    the unit the dispatcher's fan-out hands to a node."""
     nodes = pgt.node_ids[ids]
     order = np.argsort(nodes, kind="stable")
     run = ids[order]
@@ -196,6 +224,27 @@ class _FencedDataRef(_DataRef):
         super().write(value)
 
 
+class _StagedRef(_DataRef):
+    """Output ref that buffers writes for the landing instead of touching
+    the payload table (reads see the buffer first)."""
+
+    __slots__ = ("buf",)
+
+    def __init__(self, session: CompiledSession, idx: int,
+                 buf: List[Tuple[int, Any]]) -> None:
+        super().__init__(session, idx)
+        self.buf = buf
+
+    def write(self, value: Any) -> None:
+        self.buf.append((self.idx, value))
+
+    def read(self) -> Any:
+        for j, v in reversed(self.buf):
+            if j == self.idx:
+                return v
+        return super().read()
+
+
 class _AppRef(CompiledDropRef):
     """Duck-types the slice of ``AppDrop`` an app function consumes
     (``app.meta`` with oid/construct/params, ``app.uid``, ``app.node``,
@@ -247,16 +296,20 @@ def _drop_meta(pgt: CompiledPGT, idx: int) -> Dict[str, Any]:
 
 
 class _Dispatch:
-    """Precomputed dispatch tables + the per-wave app execution logic."""
+    """Precomputed dispatch tables + the per-wave app execution logic:
+    the one place a registry app runs (``_run_apps`` → ``_run_batch`` →
+    ``_attempt``) and lands (``_land``)."""
 
     def __init__(self, session: CompiledSession,
                  hooks: Optional[ExecHooks] = None,
                  executors: Optional[Dict[str, Any]] = None,
-                 stream_table: Optional[StreamTable] = None) -> None:
+                 stream_table: Optional[StreamTable] = None,
+                 policy: Optional[DispatchPolicy] = None) -> None:
         pgt = session.pgt
         self.s = session
         self.pgt = pgt
         self.hooks = hooks
+        self.policy = policy if policy is not None else DispatchPolicy()
         # node name -> thread pool: Python-app waves spanning several
         # nodes overlap (one worker task per node batch); None/empty
         # keeps the sequential in-thread dispatch
@@ -316,9 +369,16 @@ class _Dispatch:
             self.stream_prod: Optional[np.ndarray] = prod
         else:
             self.stream_prod = None
+        # stream producers and consumers write through the payload table
+        # while they run (their chunks must reach the rings during the
+        # app): they run in this process, once, and are never duplicated
+        masks = [m for m in (self.stream_prod, self.stream_cons)
+                 if m is not None]
+        self.write_through = set(np.flatnonzero(
+            np.logical_or.reduce(masks)).tolist()) if masks else set()
         self.deadline = float("inf")   # set per run by execute_frontier
         # telemetry (off unless the session carries a Timeline/registry):
-        # fast paths stamp whole batches, _run_python stamps per app
+        # fast paths stamp whole batches, _land stamps per app
         self.tl = session.timeline
         self.wave = 0                  # current wave index, for stamps
         self.m_batches = None          # Counter("exec.dispatch_batches")
@@ -331,8 +391,8 @@ class _Dispatch:
         concurrently in the object engine, so one ``max(seconds)`` sleep
         models it — NOT one per node); everything else goes out as one
         batched dispatch per node.  Registry (Python) apps of the whole
-        wave are dispatched together, node-sorted, so a resilience runner
-        can overlap per-node batches and speculate across nodes."""
+        wave are dispatched together, node-sorted, so their node batches
+        overlap and speculation can duplicate across nodes."""
         if run_ids.size == 0:
             return
         codes = self.codes_of(run_ids)
@@ -342,15 +402,11 @@ class _Dispatch:
             run_ids = run_ids[codes != CODE_SLEEP]
             if run_ids.size == 0:
                 return
-        nodes = self.pgt.node_ids[run_ids]
-        order = np.lexsort((run_ids, nodes))
-        run = run_ids[order]
-        bounds = np.flatnonzero(np.diff(nodes[order])) + 1
-        batches = np.split(run, bounds)
+        batches = node_batches(self.pgt, np.sort(run_ids))
         if self.m_batches is not None:
             self.m_batches.inc(len(batches))
         python_parts = [self._dispatch_batch(batch) for batch in batches]
-        self._run_python_batch(np.concatenate(python_parts))
+        self._run_apps(np.concatenate(python_parts))
 
     def codes_of(self, ids: np.ndarray) -> np.ndarray:
         """Dispatch codes for a batch, with stream producers forced onto
@@ -389,93 +445,6 @@ class _Dispatch:
             self._identity_batch(ident_ids)
         return batch[codes == CODE_PYTHON]
 
-    def _run_python_batch(self, ids: np.ndarray) -> None:
-        """Registry-path dispatch, deadline-checked per app (a wide wave
-        of Python apps must not overshoot the execution timeout).
-
-        A resilience ``python_runner`` hook takes over the whole per-node
-        batch (threaded dispatch, retries, straggler speculation);
-        otherwise, with node executors available, per-node batches run
-        concurrently on the node thread pools — the object engine's wave
-        parallelism, which the plain sequential loop used to serialise."""
-        if self.tl is not None and ids.size:
-            self.tl.stamp_ready(ids, time.monotonic())
-        if ids.size and self.hooks is not None \
-                and self.hooks.python_runner is not None:
-            self.hooks.python_runner(self, ids)
-            return
-        if self.executors and ids.size and (self.proc_nodes or ids.size > 1):
-            self._run_python_threaded(ids)
-            return
-        self._run_python_seq(ids)
-
-    def _run_python_seq(self, ids: np.ndarray) -> None:
-        for i in ids.tolist():
-            if time.monotonic() > self.deadline:
-                raise _WaveTimeout
-            self._run_python(i)
-
-    def _run_python_threaded(self, ids: np.ndarray) -> None:
-        """Overlap the wave's per-node batches on the node thread pools.
-
-        Every app still lands in a terminal state exactly as on the
-        sequential path (``_run_python`` catches app exceptions); batches
-        on nodes without an executor (or unplaced drops) run inline.  A
-        deadline overrun in any batch surfaces as one ``_WaveTimeout``
-        after all batches stopped — the state array stays resumable."""
-        batches = node_batches(self.pgt, ids)
-        if len(batches) <= 1 and not self.proc_nodes:
-            self._run_python_seq(ids)
-            return
-        node_ids = self.pgt.node_ids
-        names = self.pgt.node_names
-        futures = []
-        inline: List[np.ndarray] = []
-        for batch in batches:
-            nid = int(node_ids[int(batch[0])])
-            ex = self.executors.get(names[nid]) if nid >= 0 else None
-            if ex is None:
-                inline.append(batch)
-            elif hasattr(ex, "run_batch"):
-                # process-backed node: ship the batch to the worker, except
-                # stream producers/consumers — their chunk-granular writes
-                # must land in the parent's rings as they happen
-                keep = np.ones(batch.size, dtype=bool)
-                if self.stream_prod is not None:
-                    keep &= ~self.stream_prod[batch]
-                if self.stream_cons is not None:
-                    keep &= ~self.stream_cons[batch]
-                local = batch[~keep]
-                remote = batch[keep]
-                if local.size:
-                    inline.append(local)
-                if remote.size:
-                    futures.append(
-                        ex.submit(self._run_proc_batch, remote, ex, nid))
-            else:
-                futures.append(ex.submit(self._run_python_seq, batch))
-        timed_out = False
-        lost: List[str] = []
-        for batch in inline:
-            try:
-                self._run_python_seq(batch)
-            except _WaveTimeout:
-                timed_out = True     # keep draining; workers stop on the
-                #                      same deadline within one app each
-        for f in futures:
-            try:
-                f.result()
-            except _WaveTimeout:
-                timed_out = True
-            except WorkerLost as wl:
-                lost.extend(wl.nodes)
-        if lost:
-            # takes precedence over a deadline overrun: drops on the lost
-            # node(s) can never finish without recovery
-            raise WorkerLost(sorted(set(lost)))
-        if timed_out:
-            raise _WaveTimeout
-
     # -- fast paths ---------------------------------------------------------
     def _write_none_outputs(self, ids: np.ndarray,
                             t0: Optional[float] = None) -> None:
@@ -483,7 +452,7 @@ class _Dispatch:
         ``t0`` carries a caller's earlier start stamp (the sleep batch
         starts *before* it sleeps)."""
         if not self.fast_ok:
-            self._run_python_batch(ids)
+            self._run_apps(ids)
             return
         s = self.s
         start = (time.monotonic() if t0 is None else t0) \
@@ -500,9 +469,9 @@ class _Dispatch:
         compiled engine models ideal parallelism: sleep the max once.
 
         On the registry fallback (file payloads present) each app sleeps
-        individually inside ``_run_python`` — no batched sleep on top."""
+        individually on the registry path — no batched sleep on top."""
         if not self.fast_ok:
-            self._run_python_batch(ids)
+            self._run_apps(ids)
             return
         t0 = time.monotonic() if self.tl is not None else None
         secs = max(self._sleep_seconds(i) for i in ids.tolist())
@@ -522,13 +491,13 @@ class _Dispatch:
 
     def _identity_batch(self, ids: np.ndarray) -> None:
         if not self.fast_ok:
-            self._run_python_batch(ids)
+            self._run_apps(ids)
             return
         t0 = time.monotonic() if self.tl is not None else 0.0
         s = self.s
         single = ids[self.in_deg[ids] == 1]
         # multi-input: general list semantics via the registry path
-        self._run_python_batch(ids[self.in_deg[ids] != 1])
+        self._run_apps(ids[self.in_deg[ids] != 1])
         if single.size == 0:
             return
         preds = self.in_cols[self.in_indptr[single]]
@@ -536,7 +505,7 @@ class _Dispatch:
         readable = s.payload_present[preds] | \
             (s.payload_kind[preds] == PK_NULL)
         hard = completed & ~readable     # absent payload -> PayloadError
-        self._run_python_batch(single[hard])
+        self._run_apps(single[hard])
         fast = ~hard
         vals = np.empty(single.size, dtype=object)
         easy = completed & readable
@@ -557,9 +526,9 @@ class _Dispatch:
     def app_call(self, i: int, out_ref=_DataRef):
         """(func, in_refs, out_refs, app_ref) for registry app ``i``.
 
-        ``func`` is None for no-app drops (complete without work).  The
-        resilience runner passes a staging ``out_ref`` so speculative
-        duplicates buffer writes instead of touching the payload table."""
+        ``func`` is None for no-app drops (complete without work).
+        ``_attempt`` passes a staging ``out_ref``, so writes wait for the
+        landing instead of touching the payload table."""
         s = self.s
         pgt = self.pgt
         name = pgt.app_of(i)
@@ -580,35 +549,6 @@ class _Dispatch:
         outs = [out_ref(s, int(j)) for j in
                 self.out_cols[self.out_indptr[i]:self.out_indptr[i + 1]]]
         return func, refs, outs, _AppRef(s, int(i))
-
-    def _run_python(self, i: int) -> None:
-        s = self.s
-        t0 = time.monotonic() if self.tl is not None else 0.0
-        try:
-            func, refs, outs, app = self.app_call(i)
-            if func is not None:
-                if getattr(func, "streaming", False):
-                    # streaming-marked func on the batch path (streaming
-                    # disabled, or wired batch-only): chunks were never
-                    # delivered; run only the finalizer, as the object
-                    # oracle's AppDrop.execute does
-                    fin = getattr(func, "finish", None)
-                    if fin is not None:
-                        fin(refs, outs, app)
-                else:
-                    func(refs, outs, app)
-            s.drop_state[i] = ST_COMPLETED
-        except _WaveTimeout:
-            raise
-        except StreamAbort:
-            # a chunk push aborted (run shutting down / past deadline):
-            # resumable, not an app failure
-            raise _WaveTimeout
-        except Exception:  # noqa: BLE001 - app failures become drop ERRORs
-            s.drop_state[i] = ST_ERROR
-            s.record_error(i, traceback.format_exc(limit=8))
-        if self.tl is not None:
-            self.tl.stamp(int(i), t0, time.monotonic(), self.wave)
 
     # -- process-backed dispatch (ProcExecutor mailbox) ----------------------
     def proc_spec(self, i: int) -> Dict[str, Any]:
@@ -653,63 +593,259 @@ class _Dispatch:
             for j in self.out_cols[self.out_indptr[i]:self.out_indptr[i + 1]]]
         return spec
 
-    def _run_proc_batch(self, batch: np.ndarray, ex: Any, nid: int) -> None:
-        """Ship one node batch to its worker process and apply the reply.
+    # -- one attempt: in this process, or in the node's worker ---------------
+    def _attempt(self, ids: List[int], proc: Any = None):
+        """Run ``ids`` once, yielding ``(i, writes, error, t0, t1)`` per app
+        as it finishes.  In this process the outputs are staged into
+        ``writes`` (write-through apps excepted); on a process-backed node
+        (``proc``) the batch ships in one ``run_batch`` and the stamps are
+        the worker's CLOCK_MONOTONIC, comparable across Linux processes.
+        Raises ``_WaveTimeout`` past the deadline (apps not yet yielded
+        stay INIT) and :class:`WorkerLost` if the worker dies."""
+        if proc is not None:
+            yield from self._attempt_proc(ids, proc)
+            return
+        for i in ids:
+            if time.monotonic() > self.deadline:
+                raise _WaveTimeout
+            buf: List[Tuple[int, Any]] = []
+            out_ref = _DataRef if i in self.write_through else \
+                (lambda s, j: _StagedRef(s, j, buf))
+            t0 = time.monotonic()
+            err = None
+            try:
+                func, refs, outs, app = self.app_call(i, out_ref)
+                if func is not None:
+                    if getattr(func, "streaming", False):
+                        # streaming-marked func on the batch path (streaming
+                        # disabled, or wired batch-only): chunks were never
+                        # delivered; run only the finalizer, as the object
+                        # oracle's AppDrop.execute does
+                        fin = getattr(func, "finish", None)
+                        if fin is not None:
+                            fin(refs, outs, app)
+                    else:
+                        func(refs, outs, app)
+            except _WaveTimeout:
+                raise
+            except StreamAbort:
+                # a chunk push aborted (run shutting down / past deadline):
+                # resumable, not an app failure
+                raise _WaveTimeout
+            except Exception:  # noqa: BLE001 - app failures become drop ERRORs
+                err = traceback.format_exc(limit=8)
+            yield i, buf, err, t0, time.monotonic()
 
-        Raises :class:`WorkerLost` if the worker dies (caller drains all
-        batches first) and ``_WaveTimeout`` on budget exhaustion — drops
-        the worker never reached stay INIT, so the run is resumable."""
-        s = self.s
+    def _attempt_proc(self, ids: List[int], proc: Any):
         specs: List[Dict[str, Any]] = []
-        for i in batch.tolist():
+        for i in ids:
             spec = self.proc_spec(i)
             tb = spec.get("parent_tb")
             if tb is not None:
                 t = time.monotonic()
-                s.drop_state[i] = ST_ERROR
-                s.record_error(i, tb)
-                if self.tl is not None:
-                    self.tl.stamp(int(i), t, t, self.wave, node=nid)
+                yield i, (), tb, t, t
             else:
                 specs.append(spec)
         budget = self.deadline - time.monotonic()
         if budget <= 0:
             raise _WaveTimeout
-        results = ex.run_batch(specs, budget)
-        if self._apply_proc_results(results, nid):
-            raise _WaveTimeout
-
-    def _apply_proc_results(self, results: List[Dict[str, Any]],
-                            nid: int) -> bool:
-        """Replay worker results into the session; True if any timed out.
-
-        Concurrent calls (one per node thread) touch row-disjoint state,
-        the same contract as the threaded in-process dispatch.  Worker
-        stamps are CLOCK_MONOTONIC, comparable across Linux processes, so
-        they merge into the Timeline unadjusted."""
-        s = self.s
         timed_out = False
-        for r in results:
-            i = int(r["idx"])
-            status = r["status"]
-            if status == "timeout":
+        for r in proc.run_batch(specs, budget):
+            if r["status"] == "timeout":
                 timed_out = True
                 continue
-            if status == "ok":
+            ok = r["status"] == "ok"
+            t1 = r.get("t1", time.monotonic())
+            yield (int(r["idx"]), r["writes"] if ok else (),
+                   None if ok else r["tb"], r.get("t0", t1), t1)
+        if timed_out:
+            raise _WaveTimeout
+
+    # -- a node batch under the retry policy, and the landing ----------------
+    def _run_batch(self, ids: List[int], nid: int, proc: Any, epoch: int,
+                   speculative: bool = False) -> None:
+        """Run ``ids`` on node ``nid`` and land each result; the retry
+        policy re-runs only the failed apps (never a write-through app,
+        whose writes already reached the payload table).  A retried app's
+        stamp starts at its first attempt."""
+        pol = self.policy
+        first: Dict[int, float] = {}
+        for k in range(pol.max_attempts):
+            again: List[int] = []
+            for i, writes, err, t0, t1 in self._attempt(ids, proc):
+                t0 = first.get(i, t0)
+                if err is not None and k + 1 < pol.max_attempts \
+                        and i not in self.write_through:
+                    first[i] = t0
+                    again.append(i)
+                else:
+                    self._land(i, writes, err, t0, t1, nid, epoch,
+                               speculative)
+            if not again:
+                return
+            with pol.lock:
+                pol.count(self.s, "retries", len(again))
+            if pol.backoff:          # no sleep after the final attempt
+                time.sleep(pol.backoff * (2 ** k))
+            ids = again
+
+    def _land(self, i: int, writes, err: Optional[str], t0: float,
+              t1: float, nid: int, epoch: int,
+              speculative: bool = False) -> None:
+        """Enter one registry app's result into the session: its payload
+        writes, its state row, its error and its Timeline stamp on the
+        node ``nid`` that executed it.
+
+        First writer wins under the policy's lock, and only in the epoch
+        the attempt started in: a recovery in between reset the row for
+        re-execution.  A failed attempt's staged writes are dropped, and a
+        speculative duplicate lands only a success."""
+        s, pol = self.s, self.policy
+        with pol.lock:
+            if epoch != pol.epoch or s.drop_state[i] != ST_INIT \
+                    or (speculative and err is not None):
+                if speculative:
+                    pol.count(s, "speculative_losses")
+                return
+            if err is None:
                 try:
-                    for j, v in r["writes"]:
+                    for j, v in writes:
                         s._write_idx(int(j), v)
-                    s.drop_state[i] = ST_COMPLETED
-                except Exception:  # noqa: BLE001 - replay failure -> ERROR
-                    s.drop_state[i] = ST_ERROR
-                    s.record_error(i, traceback.format_exc(limit=8))
+                except Exception:  # noqa: BLE001 - spill failures (file
+                    # payload mkdir/pickle) become drop ERRORs
+                    err = traceback.format_exc(limit=8)
+            if err is None:
+                s.drop_state[i] = ST_COMPLETED
+                if speculative:
+                    pol.count(s, "speculative_wins")
             else:
                 s.drop_state[i] = ST_ERROR
-                s.record_error(i, r["tb"])
+                s.record_error(i, err)
             if self.tl is not None:
-                t1 = r.get("t1", time.monotonic())
-                self.tl.stamp(i, r.get("t0", t1), t1, self.wave, node=nid)
-        return timed_out
+                self.tl.stamp(int(i), t0, t1, self.wave, node=nid)
+
+    # -- the fan-out ----------------------------------------------------------
+    def _run_apps(self, ids: np.ndarray) -> None:
+        """Run the wave's registry apps and leave each one terminal, or
+        raise :class:`WorkerLost` (it outranks a deadline overrun: drops
+        on a lost node never finish without recovery), else
+        ``_WaveTimeout``; either leaves the state array resumable.
+
+        A wave with one node batch and no process-backed node runs inline
+        in this thread, in node-sorted order.  Otherwise each node batch
+        is a task on its node's executor; a batch whose node has none
+        (dead, unplaced) runs inline after the submits.  Under
+        speculation each app is its own task, and this thread polls the
+        wave and duplicates the overdue ones."""
+        if ids.size == 0:
+            return
+        if self.tl is not None:
+            self.tl.stamp_ready(ids, time.monotonic())
+        pgt, spec, wt = self.pgt, self.policy.speculation, self.write_through
+        epoch = self.policy.epoch
+        batches = node_batches(pgt, ids)
+        if len(batches) == 1 and not self.proc_nodes and spec is None:
+            self._run_batch(ids.tolist(), int(pgt.node_ids[int(ids[0])]),
+                            None, epoch)
+            return
+        lost: set = set()
+        started: Dict[int, float] = {}
+        futures, inline = [], []
+        for batch in batches:
+            nid = int(pgt.node_ids[int(batch[0])])
+            node = pgt.node_names[nid] if nid >= 0 else None
+            ex = self.executors.get(node)
+            run = batch.tolist()
+            if ex is None:
+                inline.append((run, nid))
+                continue
+            proc = ex if hasattr(ex, "run_batch") else None
+            parts = [(run, proc)]
+            if proc is not None and wt:
+                # write-through apps run in this process, on the node's pool
+                parts = [([i for i in run if i not in wt], proc),
+                         ([i for i in run if i in wt], None)]
+            for part, p in parts:
+                if not part:
+                    continue
+                if spec is None:
+                    futures.append(
+                        ex.submit(self._task, part, nid, p, epoch, lost))
+                    continue
+                spec.start(node, len(part))
+                for i in part:
+                    ex.submit(self._spec_task, i, node, nid, p, epoch, lost,
+                              started)
+        for run, nid in inline:
+            self._task(run, nid, None, epoch, lost)
+        for f in futures:
+            f.result()
+        if spec is not None:
+            self._watch(ids, started, epoch, lost)
+        if lost:
+            raise WorkerLost(sorted(lost))
+        if (self.s.drop_state[ids] == ST_INIT).any():
+            raise _WaveTimeout
+
+    def _task(self, ids: List[int], nid: int, proc: Any, epoch: int,
+              lost: Optional[set]) -> None:
+        """One fan-out task: run and land ``ids`` on node ``nid``.  A lost
+        worker goes into ``lost``; ``lost`` None marks a speculative
+        duplicate, which a lost worker or the deadline just loses."""
+        try:
+            self._run_batch(ids, nid, proc, epoch, speculative=lost is None)
+        except (WorkerLost, _WaveTimeout) as exc:
+            if lost is None:
+                with self.policy.lock:
+                    self.policy.count(self.s, "speculative_losses")
+            elif isinstance(exc, WorkerLost):
+                lost.update(exc.nodes)
+
+    # -- straggler speculation ------------------------------------------------
+    def _spec_task(self, i: int, node: str, nid: int, proc: Any, epoch: int,
+                   lost: Optional[set],
+                   started: Optional[Dict[int, float]] = None) -> None:
+        """App ``i`` as its own task on ``node``: a primary (``started``
+        given; clocked from when it starts, since queue wait is not
+        slowness) or a duplicate (``lost`` None)."""
+        t0 = time.monotonic()
+        if started is not None:
+            started[i] = t0
+        try:
+            self._task([i], nid, proc, epoch, lost)
+        finally:
+            self.policy.speculation.done(
+                node, None if started is None else t0)
+
+    def _watch(self, ids: np.ndarray, started: Dict[int, float], epoch: int,
+               lost: set) -> None:
+        """Poll the wave until its apps are terminal, a worker is lost or
+        the deadline passes.  Each app running longer than the threshold
+        is duplicated once, onto the least-loaded live node other than
+        its own (into that node's worker, if it has one)."""
+        spec, pgt = self.policy.speculation, self.pgt
+        state = self.s.drop_state
+        speculated: set = set()
+        while not lost and time.monotonic() <= self.deadline:
+            pending = ids[state[ids] == ST_INIT]
+            if pending.size == 0:
+                return
+            threshold = spec.threshold()
+            now = time.monotonic()
+            for i in pending.tolist() if threshold is not None else ():
+                t0 = started.get(i)       # None = still queued, not slow
+                if t0 is None or now - t0 <= threshold \
+                        or i in speculated or i in self.write_through:
+                    continue
+                speculated.add(i)
+                nm = spec.target(pgt.node_names[pgt.node_ids[i]])
+                if nm is not None:
+                    ex = nm.executor
+                    ex.submit(self._spec_task, i, nm.name,
+                              pgt.node_id_for(nm.name),
+                              ex if hasattr(ex, "run_batch") else None,
+                              epoch, None)
+            time.sleep(spec.poll)
 
 
 # ---------------------------------------------------------------------------
@@ -756,6 +892,7 @@ class _StreamLane:
         # lane generation: if shutdown leaves a consumer thread alive it
         # fences the table, and refs/loops of this generation go inert
         self.gen = table.generation
+        self.epoch = ctx.policy.epoch
         self.join_grace = float(table.config.shutdown_grace_s)
         self.hooks = ctx.hooks
         self.threads: Dict[int, threading.Thread] = {}
@@ -895,26 +1032,21 @@ class _StreamLane:
     def _finalize(self, c: int) -> None:
         if self.table.generation != self.gen:
             return                # fenced: a fresh lane owns this consumer
-        s = self.s
         ctx = self.ctx
         t0 = self.first_t0.get(c, time.monotonic())
         tb = self.errored.get(c)
-        if tb is not None:
-            s.drop_state[c] = ST_ERROR
-            s.record_error(c, tb)
-        else:
+        if tb is None:
             try:
                 func, refs, outs, _ = ctx.app_call(c)
                 fin = getattr(func, "finish", None) \
                     if func is not None else None
                 if fin is not None:
                     fin(refs, outs, self.app_ref(c))
-                s.drop_state[c] = ST_COMPLETED
             except Exception:  # noqa: BLE001 - finaliser failure -> ERROR
-                s.drop_state[c] = ST_ERROR
-                s.record_error(c, traceback.format_exc(limit=8))
-        if ctx.tl is not None:
-            ctx.tl.stamp(c, t0, time.monotonic(), ctx.wave)
+                tb = traceback.format_exc(limit=8)
+        # the finaliser wrote through its fenced refs: nothing to stage
+        ctx._land(c, (), tb, t0, time.monotonic(),
+                  int(ctx.pgt.node_ids[c]), self.epoch)
         ev = self.done.get(c)
         if ev is not None:
             ev.set()
@@ -971,13 +1103,19 @@ def execute_frontier(session: CompiledSession,
                      timeout: float = 60.0,
                      hooks: Optional[ExecHooks] = None,
                      executors: Optional[Dict[str, Any]] = None,
-                     stream: Union[StreamConfig, bool, None] = None) -> bool:
+                     stream: Union[StreamConfig, bool, None] = None,
+                     policy: Optional[DispatchPolicy] = None) -> bool:
     """Run a deployed :class:`CompiledSession` to completion, wave-by-wave.
 
     ``executors`` (node name -> thread pool, e.g.
     ``MasterDropManager.node_executors()``) lets registry-app waves that
     span several nodes overlap; without it Python apps run sequentially
     in the calling thread.  Vectorised fast paths are unaffected.
+
+    ``policy`` (a :class:`DispatchPolicy`) applies retry and straggler
+    speculation to the registry apps; ``execute_resilient`` passes the
+    one it keeps across resumes.  Without one the call gets a fresh
+    default: one attempt, no speculation.
 
     ``stream`` controls the chunk-granular streaming lane: ``None``
     (default) auto-enables it when the graph has active streaming edges,
@@ -1001,10 +1139,12 @@ def execute_frontier(session: CompiledSession,
     """
     tl = session.timeline
     if tl is None:
-        return _execute_waves(session, timeout, hooks, executors, stream)
+        return _execute_waves(session, timeout, hooks, executors, stream,
+                              policy)
     t0 = tl.begin_execute()
     try:
-        return _execute_waves(session, timeout, hooks, executors, stream)
+        return _execute_waves(session, timeout, hooks, executors, stream,
+                              policy)
     finally:
         tl.end_execute(t0)
 
@@ -1012,7 +1152,8 @@ def execute_frontier(session: CompiledSession,
 def _execute_waves(session: CompiledSession, timeout: float,
                    hooks: Optional[ExecHooks],
                    executors: Optional[Dict[str, Any]],
-                   stream: Union[StreamConfig, bool, None]) -> bool:
+                   stream: Union[StreamConfig, bool, None],
+                   policy: Optional[DispatchPolicy]) -> bool:
     """The body of :func:`execute_frontier`: the waves of one call."""
     pgt = session.pgt
     n = pgt.num_drops
@@ -1048,7 +1189,8 @@ def _execute_waves(session: CompiledSession, timeout: float,
                         "exec.streaming_edges_degraded").inc(n_active)
 
     in_deg = pgt.in_degrees()
-    ctx = _Dispatch(session, hooks, executors, stream_table=tbl)
+    ctx = _Dispatch(session, hooks, executors, stream_table=tbl,
+                    policy=policy)
     out_indptr, out_cols = ctx.out_indptr, ctx.out_cols
 
     # readiness counters, derived from current state (fresh start or resume)

@@ -475,7 +475,7 @@ class ProcExecutor:
         self._dead = False
         self._batch_seq = 0
 
-    # -- thread-pool facade (ResilientRunner, AppDrop call sites) ----------
+    # -- thread-pool facade (the dispatcher's tasks, AppDrop call sites) ---
     def submit(self, fn: Callable[..., Any], /, *args: Any, **kwargs: Any) -> Future:
         return self._threads.submit(fn, *args, **kwargs)
 

@@ -14,17 +14,17 @@ compiled path's 100x throughput advantage lives:
   rows in bulk and lets ``execute_frontier`` resume mid-wave — the
   scheduler re-derives its readiness counters from the state array.
 
-* **Straggler speculation** — :class:`ResilientRunner` plugs into the
-  dispatch layer (``ExecHooks.python_runner``): per-node wave batches run
-  on the node's thread pool with deadline tracking; an app slower than
-  ``factor`` x the median completed duration is duplicated onto the
-  least-loaded live node, and the first writer commits into the dense
-  payload table (the loser's buffered writes are discarded — no payload
-  corruption, unlike raw double-execution).
+* **Straggler speculation** — a policy of the compiled dispatcher
+  (``exec_compiled.DispatchPolicy``): each app of a wave is its own task
+  on its node's pool, and an app slower than ``factor`` x the median
+  completed duration is duplicated onto the least-loaded live node
+  (:class:`_Speculation` keeps that state).  The first writer lands in
+  the dense payload table; the loser's staged writes are dropped — no
+  payload corruption, unlike raw double-execution.
 
-* **Bounded retry** — a dispatch-layer policy (exponential backoff, no
-  terminal sleep) instead of the object path's per-app ``with_retries``
-  wrapper.
+* **Bounded retry** — the same dispatcher re-runs a node batch's failed
+  apps (exponential backoff, no terminal sleep) instead of the object
+  path's per-app ``with_retries`` wrapper.
 
 The object engine remains the semantic oracle: compiled recovery must
 produce the same final status counts and payload values as
@@ -36,24 +36,22 @@ from __future__ import annotations
 import statistics
 import threading
 import time
-import traceback
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
-from .exec_compiled import ExecHooks, _DataRef, _WaveTimeout, \
-    execute_frontier, node_batches
+from .exec_compiled import DispatchPolicy, ExecHooks, execute_frontier
 from .managers import MasterDropManager
-from .pgt import KIND_DATA, CompiledPGT, csr_gather
+from .pgt import KIND_DATA, csr_gather
 from .procpool import WorkerLost
-from .session import (PK_FILE, PK_MEMORY, PK_NULL, ST_COMPLETED, ST_ERROR,
-                      ST_INIT, CompiledSession)
+from .session import (PK_FILE, PK_MEMORY, PK_NULL, ST_COMPLETED, ST_INIT,
+                      CompiledSession)
 
 __all__ = [
     "CompiledFaultManager", "FailureScript", "NodeFailureInterrupt",
-    "ResilienceConfig", "ResilienceStats", "ResilientRunner", "RetryPolicy",
+    "ResilienceConfig", "ResilienceStats", "RetryPolicy",
     "StragglerPolicy", "execute_resilient",
 ]
 
@@ -95,10 +93,6 @@ class ResilienceConfig:
     failures: List[FailureScript] = field(default_factory=list)
     stragglers: Optional[StragglerPolicy] = None
     retry: Optional[RetryPolicy] = None
-
-    @property
-    def needs_runner(self) -> bool:
-        return self.stragglers is not None or self.retry is not None
 
 
 @dataclass
@@ -308,179 +302,54 @@ class CompiledFaultManager:
 
 
 # ---------------------------------------------------------------------------
-# Straggler speculation + retry — the dispatch-layer runner
+# Straggler speculation — the state the dispatcher's fan-out consults
 # ---------------------------------------------------------------------------
 
 
-class _StagedRef(_DataRef):
-    """Output ref that buffers writes instead of touching the payload
-    table — the commit happens atomically, first-writer-wins."""
+class _Speculation:
+    """What ``_Dispatch`` needs to speculate: the running threshold and
+    the least-loaded live node a duplicate goes to, with the per-node
+    count of in-flight tasks behind both."""
 
-    __slots__ = ("buf",)
-
-    def __init__(self, session: CompiledSession, idx: int,
-                 buf: List[Tuple[int, object]]) -> None:
-        super().__init__(session, idx)
-        self.buf = buf
-
-    def write(self, value) -> None:
-        self.buf.append((self.idx, value))
-
-    def read(self):
-        for j, v in reversed(self.buf):
-            if j == self.idx:
-                return v
-        return super().read()
-
-
-class ResilientRunner:
-    """``ExecHooks.python_runner``: threaded per-node dispatch with
-    bounded retry and straggler speculation.
-
-    The wave's Python apps arrive node-sorted; each node's batch is
-    submitted to that node's thread pool (all nodes overlap — the object
-    engine's wave parallelism, which the plain compiled path serialises).
-    The dispatching thread tracks per-app deadlines against the running
-    median and duplicates overdue apps onto the least-loaded live node.
-    Both the primary and the duplicate run with *staged* output refs;
-    whoever finishes first commits its buffer into the dense payload
-    table under one lock and flips the state row — the loser's commit is
-    a no-op and its writes are dropped.
-    """
-
-    def __init__(self, master: MasterDropManager, config: ResilienceConfig,
-                 stats: ResilienceStats) -> None:
+    def __init__(self, master: MasterDropManager,
+                 policy: StragglerPolicy) -> None:
         self.master = master
-        self.retry = config.retry
-        self.strag = config.stragglers
-        self.stats = stats
+        self.policy = policy
+        self.poll = policy.poll
         self._lock = threading.Lock()
         # bounded window: the straggler threshold tracks recent behaviour
         # and the per-poll median stays O(window), not O(run history)
         self._durations: deque = deque(maxlen=256)
         self._rr = 0                      # round-robin tie-break cursor
         self._inflight: Dict[str, int] = {}
-        # bumped by fault recovery (invalidate()): work started before a
-        # recovery must never commit into the reset state rows
-        self._epoch = 0
 
-    def invalidate(self) -> None:
-        """Discard all in-flight work at commit time (called after a
-        node-failure recovery reset state rows to INIT — a leftover
-        primary/duplicate thread committing a stale pre-failure buffer
-        would otherwise flip a reset drop COMPLETED behind the resumed
-        scheduler's back and stall its successors)."""
+    def start(self, node: str, n: int) -> None:
         with self._lock:
-            self._epoch += 1
+            self._inflight[node] = self._inflight.get(node, 0) + n
 
-    # -- entry (the wave's Python apps, node-sorted) -----------------------
-    def __call__(self, ctx, ids: np.ndarray) -> None:
-        if self.strag is None:
-            for i in ids.tolist():
-                if time.monotonic() > ctx.deadline:
-                    raise _WaveTimeout
-                epoch = self._epoch
-                t0 = time.monotonic()
-                self._commit(ctx, int(i), *self._attempts(ctx, int(i)),
-                             epoch=epoch, t0=t0)
-            return
-        self._threaded_wave(ctx, ids)
+    def done(self, node: str, t0: Optional[float] = None) -> None:
+        """A task on ``node`` ended; a primary's wall since ``t0`` feeds
+        the threshold."""
+        with self._lock:
+            self._inflight[node] = self._inflight.get(node, 1) - 1
+            if t0 is not None:
+                self._durations.append(time.monotonic() - t0)
 
-    def _threaded_wave(self, ctx, ids: np.ndarray) -> None:
-        pgt = ctx.pgt
-        nms = self.master.node_managers()
-        # filled by the worker when the app actually STARTS running —
-        # queue wait must not count toward the straggler deadline (the
-        # object-path watcher clocks from the RUNNING event, and
-        # mass-speculating a deep queued batch doubles the wave's work)
-        started: Dict[int, float] = {}
-        speculated: Set[int] = set()
-        home: Dict[int, str] = {}
-
-        # one epoch for the whole wave, captured before any submit: a
-        # recovery can only happen at a wave boundary, so any work from
-        # this wave that outlives one is stale by construction
-        epoch = self._epoch
-
-        def primary(i: int, node: str) -> None:
-            t0 = time.monotonic()
-            started[i] = t0
-            try:
-                self._commit(ctx, i, *self._attempts(ctx, i), epoch=epoch,
-                             t0=t0)
-            except (WorkerLost, _WaveTimeout):
-                # drop stays INIT; the poll loop below surfaces the dead
-                # node / deadline for the whole wave
-                pass
-            finally:
-                with self._lock:
-                    self._inflight[node] = self._inflight.get(node, 1) - 1
-                    self._durations.append(time.monotonic() - t0)
-
-        # submit every node's batch — all nodes overlap
-        for batch in node_batches(pgt, ids):
-            node = pgt.node_names[int(pgt.node_ids[int(batch[0])])]
-            nm = nms.get(node)
-            if nm is None or not nm.info.alive:
-                # placement no longer live (mid-recovery edge): run inline
-                for i in batch.tolist():
-                    if time.monotonic() > ctx.deadline:
-                        raise _WaveTimeout
-                    t0 = time.monotonic()
-                    self._commit(ctx, int(i),
-                                 *self._attempts(ctx, int(i)), epoch=epoch,
-                                 t0=t0)
-                continue
-            with self._lock:
-                self._inflight[node] = \
-                    self._inflight.get(node, 0) + int(batch.size)
-            for i in batch.tolist():
-                home[int(i)] = node
-                nm.executor.submit(primary, int(i), node)
-
-        state = ctx.s.drop_state
-        while True:
-            pending = ids[state[ids] == ST_INIT]
-            if pending.size == 0:
-                return
-            # a worker process that died mid-wave leaves its drops INIT
-            # forever; surface the dead home nodes so the resilient loop
-            # recovers instead of spinning to the deadline
-            dead = sorted({home[int(i)] for i in pending.tolist()
-                           if home.get(int(i)) is not None
-                           and not nms[home[int(i)]].info.alive})
-            if dead:
-                raise WorkerLost(dead)
-            if time.monotonic() > ctx.deadline:
-                raise _WaveTimeout   # committed work stays; resumable
-            threshold = self._threshold()
-            if threshold is not None:
-                now = time.monotonic()
-                for i in pending.tolist():
-                    t0 = started.get(i)   # None = still queued, not slow
-                    if t0 is not None and i not in speculated \
-                            and now - t0 > threshold:
-                        speculated.add(i)
-                        self._speculate(ctx, i, home[i], epoch=epoch)
-            time.sleep(self.strag.poll)
-
-    # -- straggler speculation ---------------------------------------------
-    def _threshold(self) -> Optional[float]:
+    def threshold(self) -> Optional[float]:
         with self._lock:
             durs = list(self._durations)   # bounded snapshot (maxlen)
         if len(durs) < 3:
             return None
-        return max(self.strag.factor * statistics.median(durs),
-                   self.strag.min_runtime)
+        return max(self.policy.factor * statistics.median(durs),
+                   self.policy.min_runtime)
 
-    def _speculate(self, ctx, i: int, home: str,
-                   epoch: Optional[int] = None) -> None:
-        """Duplicate app ``i`` onto the least-loaded live node (round-robin
-        among ties), first-writer-wins."""
+    def target(self, home: str):
+        """The least-loaded live node other than ``home`` (round-robin
+        among ties), charged one task; None if there is none."""
         live = self.master.live_node_managers()
         cands = [nm for n, nm in sorted(live.items()) if n != home]
         if not cands:
-            return
+            return None
         with self._lock:
             low = min(self._inflight.get(nm.name, 0) for nm in cands)
             tied = [nm for nm in cands
@@ -489,174 +358,7 @@ class ResilientRunner:
             self._rr += 1
             self._inflight[target.name] = \
                 self._inflight.get(target.name, 0) + 1
-
-        wave_epoch = self._epoch if epoch is None else epoch
-
-        def dup() -> None:
-            t0 = time.monotonic()
-            try:
-                # run on the TARGET node (on a process-backed cluster the
-                # duplicate executes in the target's worker process)
-                buf, err = self._attempts(ctx, i, node=target.name)
-                if err is None:
-                    # a winning duplicate records the node that actually
-                    # executed the drop, not its original placement
-                    self._commit(ctx, i, buf, None, speculative=True,
-                                 epoch=wave_epoch, t0=t0,
-                                 node=ctx.pgt.node_id_for(target.name))
-                else:
-                    with self._lock:
-                        self.stats.speculative_losses += 1
-            except (WorkerLost, _WaveTimeout):
-                # the target died or ran out of budget: the duplicate just
-                # loses; the primary (or a recovery) still owns the drop
-                with self._lock:
-                    self.stats.speculative_losses += 1
-            finally:
-                with self._lock:
-                    self._inflight[target.name] = \
-                        self._inflight.get(target.name, 1) - 1
-
-        target.executor.submit(dup)
-
-    # -- staged execution with bounded retry -------------------------------
-    def _attempts(self, ctx, i: int, node: Optional[str] = None):
-        """Run app ``i`` with staged outputs; returns (buffer, error).
-
-        ``node`` overrides the placement node (speculative duplicates run
-        on their target).  On a process-backed node the attempt ships to
-        that node's worker; :class:`WorkerLost` propagates — a dead worker
-        is a node failure, never an app error."""
-        ex = self._proc_executor(ctx, i, node)
-        if ex is not None:
-            return self._attempts_proc(ctx, i, ex)
-        attempts = self.retry.max_attempts if self.retry else 1
-        backoff = self.retry.backoff if self.retry else 0.0
-        err: Optional[str] = None
-        for k in range(attempts):
-            buf: List[Tuple[int, object]] = []
-            try:
-                func, ins, outs, app = ctx.app_call(
-                    i, out_ref=lambda s, j: _StagedRef(s, j, buf))
-                if func is not None:
-                    if getattr(func, "streaming", False):
-                        # degraded/batch resolution of a streaming app:
-                        # run its finish stage if present, skip otherwise
-                        fin = getattr(func, "finish", None)
-                        if fin is not None:
-                            fin(ins, outs, app)
-                    else:
-                        func(ins, outs, app)
-                return buf, None
-            except Exception:  # noqa: BLE001 - becomes a drop ERROR
-                err = traceback.format_exc(limit=8)
-                if k + 1 < attempts:
-                    with self._lock:
-                        self.stats.retries += 1
-                        ctx.s.retries += 1
-                    if ctx.s.metrics is not None:
-                        ctx.s.metrics.counter("resilience.retries").inc()
-                    if backoff:          # no sleep after the final attempt
-                        time.sleep(backoff * (2 ** k))
-        return None, err
-
-    def _proc_executor(self, ctx, i: int, node: Optional[str]):
-        """The live process-backed executor app ``i`` should run on, or
-        None (thread-backed node, dead node, unplaced drop — all fall back
-        to the in-process staged path)."""
-        if node is None:
-            nid = int(ctx.pgt.node_ids[i])
-            if nid < 0:
-                return None
-            node = ctx.pgt.node_names[nid]
-        nm = self.master.node_managers().get(node)
-        if nm is None or not nm.info.alive:
-            return None
-        ex = nm.executor
-        return ex if hasattr(ex, "run_batch") else None
-
-    def _attempts_proc(self, ctx, i: int, ex):
-        """Process-backed attempt loop: same retry policy, with the app
-        executed in the node's worker and its writes returned as the
-        staged buffer for the normal first-writer-wins commit."""
-        attempts = self.retry.max_attempts if self.retry else 1
-        backoff = self.retry.backoff if self.retry else 0.0
-        err: Optional[str] = None
-        for k in range(attempts):
-            spec = ctx.proc_spec(i)
-            tb = spec.get("parent_tb")
-            if tb is not None:
-                return None, tb
-            budget = ctx.deadline - time.monotonic()
-            if budget <= 0:
-                raise _WaveTimeout
-            res = ex.run_batch([spec], budget)[0]   # WorkerLost propagates
-            if res["status"] == "ok":
-                return list(res["writes"]), None
-            if res["status"] == "timeout":
-                raise _WaveTimeout
-            err = res["tb"]
-            if k + 1 < attempts:
-                with self._lock:
-                    self.stats.retries += 1
-                    ctx.s.retries += 1
-                if ctx.s.metrics is not None:
-                    ctx.s.metrics.counter("resilience.retries").inc()
-                if backoff:
-                    time.sleep(backoff * (2 ** k))
-        return None, err
-
-    def _commit(self, ctx, i: int, buf, err: Optional[str],
-                speculative: bool = False, epoch: int = 0,
-                t0: Optional[float] = None,
-                node: Optional[int] = None) -> bool:
-        """First-writer-wins commit into the payload table + state row.
-
-        ``epoch`` is the runner epoch captured when the attempt started;
-        a recovery in between (``invalidate()``) makes the buffer stale
-        — the drop was reset to INIT for *re-execution*, and committing
-        would hide it from the resumed scheduler's frontier.
-
-        ``t0``/``node`` feed the session timeline: the *winning* attempt
-        stamps its own start time and executing node (a speculative win
-        records the duplicate's node, not the original placement)."""
-        s = ctx.s
-        with self._lock:
-            if epoch != self._epoch or s.drop_state[i] != ST_INIT:
-                if speculative:
-                    self.stats.speculative_losses += 1
-                return False
-            if err is None:
-                try:
-                    for j, v in buf:
-                        s._write_idx(j, v)
-                except Exception:  # noqa: BLE001 - spill failures (file
-                    # payload mkdir/pickle) become drop ERRORs, exactly
-                    # as the plain dispatch path records them
-                    s.drop_state[i] = ST_ERROR
-                    s.record_error(i, traceback.format_exc(limit=8))
-                    self._stamp(ctx, i, t0, node)
-                    return True
-                s.drop_state[i] = ST_COMPLETED
-                if speculative:
-                    self.stats.speculative_wins += 1
-                    s.speculative_wins += 1
-                    if s.metrics is not None:
-                        s.metrics.counter(
-                            "resilience.speculative_wins").inc()
-            else:
-                s.drop_state[i] = ST_ERROR
-                s.record_error(i, err)
-            self._stamp(ctx, i, t0, node)
-        return True
-
-    @staticmethod
-    def _stamp(ctx, i: int, t0: Optional[float],
-               node: Optional[int]) -> None:
-        if ctx.tl is not None:
-            t1 = time.monotonic()
-            ctx.tl.stamp(int(i), t1 if t0 is None else t0, t1,
-                         ctx.wave, node=node)
+        return target
 
 
 # ---------------------------------------------------------------------------
@@ -691,8 +393,11 @@ def execute_resilient(session: CompiledSession, master: MasterDropManager,
     """
     fm = fault_manager or CompiledFaultManager(session, master)
     stats = fm.stats
-    runner = ResilientRunner(master, config, stats) \
-        if config.needs_runner else None
+    # one policy across resumes: its epoch fences work left in flight
+    policy = DispatchPolicy(
+        retry=config.retry, stats=stats,
+        speculation=None if config.stragglers is None
+        else _Speculation(master, config.stragglers))
     pending = sorted(config.failures, key=lambda f: f.at_fraction)
     fired: Set[int] = set()
     user_wave = hooks.on_wave if hooks is not None else None
@@ -709,7 +414,6 @@ def execute_resilient(session: CompiledSession, master: MasterDropManager,
 
     hooks = ExecHooks(
         on_wave=on_wave if (pending or user_wave is not None) else None,
-        python_runner=runner,
         on_stream_chunk=hooks.on_stream_chunk if hooks is not None else None,
         on_backpressure=hooks.on_backpressure if hooks is not None else None)
     deadline = time.monotonic() + timeout
@@ -718,13 +422,12 @@ def execute_resilient(session: CompiledSession, master: MasterDropManager,
         if budget <= 0:
             return False, stats
         try:
-            # failure-only configs (no runner hook) still get the default
-            # threaded per-node wave overlap; recomputed per resume so
-            # freshly-dead nodes drop out of the executor map
+            # executors recomputed per resume so freshly-dead nodes drop
+            # out of the executor map
             finished = execute_frontier(
                 session, timeout=budget, hooks=hooks,
-                executors=None if runner is not None
-                else master.node_executors(), stream=stream)
+                executors=master.node_executors(), stream=stream,
+                policy=policy)
             return finished, stats
         except (NodeFailureInterrupt, WorkerLost) as nf:
             # scripted failure (wave boundary) or a real worker-process
@@ -737,9 +440,8 @@ def execute_resilient(session: CompiledSession, master: MasterDropManager,
                     # worker death already flipped info.alive via on_lost;
                     # keep the failure ledger consistent with fail_node
                     stats.failed_nodes.append(node)
-            if runner is not None:
-                # invalidate BEFORE the state reset: a leftover thread
-                # committing between recover() and a later invalidate()
-                # would pass the epoch check against just-reset rows
-                runner.invalidate()
+            # invalidate BEFORE the state reset: a leftover thread landing
+            # between recover() and a later invalidate() would pass the
+            # epoch check against just-reset rows
+            policy.invalidate()
             fm.recover()

@@ -26,7 +26,8 @@ Three layers, all off by default and enabled via :class:`TelemetryConfig`:
   histograms (no external deps), wired into ``execute_frontier`` (waves,
   frontier sizes, dispatch batches), ``EngineManager`` (admission,
   queue depth, session-latency histogram, template cache traffic) and
-  the resilience runner (retries, speculative wins, recoveries).
+  the dispatcher's resilience policies (retries, speculative wins,
+  recoveries).
 * :func:`export_chrome_trace` — Perfetto / chrome://tracing JSON: one
   track per cluster node, one slice per drop (or one aggregated slice
   per wave-batch above ``batch_threshold``), plus a pipeline-span track
@@ -138,8 +139,8 @@ class Timeline:
     ``node`` is pre-filled with the placement at allocation — the batch
     fast paths always execute on the placement node, so only scalar
     stamps ever rewrite an entry (speculative winner on a different
-    node).  ``stamp`` — used by ``_run_python`` / the resilience runner
-    around the actual app call — writes through immediately: real apps
+    node).  ``stamp`` — used where the dispatcher lands a registry app
+    (``_Dispatch._land``) — writes through immediately: real apps
     are micro-seconds-plus each, and their true per-drop timings must
     not be clobbered by a later batch replay.  Scalar and batch stamps
     always target distinct indices (one writer per drop), so replay
@@ -150,7 +151,7 @@ class Timeline:
     Where the time between apps goes:
 
     * ``t_ready`` — when the wave handed a registry app to dispatch
-      (``_Dispatch._run_python_batch``), stamped like ``stamp_batch``:
+      (``_Dispatch._run_apps``), stamped like ``stamp_batch``:
       one deferred ``(ids, t)`` per wave, its own array allocated on
       first read.  ``t_start - t_ready`` is the time a runnable app
       waited for a worker (behind its node's batch or a full pool);

@@ -11,6 +11,7 @@ are exercised on the compiled path (the object path has its own
 import multiprocessing
 import os
 import signal
+import sys
 import threading
 import time
 
@@ -18,10 +19,11 @@ import numpy as np
 import pytest
 
 from repro.core import (AppDrop, AppState, CompiledFaultManager,
-                        CompiledSession, DropState, FailureScript, Pipeline,
-                        ResilienceConfig, RetryPolicy, StragglerPolicy,
-                        StragglerWatcher, execute_frontier, register_app,
-                        with_retries)
+                        CompiledSession, DropState, EngineConfig,
+                        FailureScript, Pipeline, ResilienceConfig,
+                        RetryPolicy, StragglerPolicy, StragglerWatcher,
+                        TelemetryConfig, Timeline, execute_frontier,
+                        execute_resilient, register_app, with_retries)
 from repro.dsl import GraphBuilder
 
 
@@ -422,6 +424,214 @@ class TestRetryPolicy:
         with pytest.raises(ValueError, match="compiled"):
             Pipeline(execution="objects",
                      resilience=ResilienceConfig())
+
+
+# ---------------------------------------------------------------------------
+# one dispatcher: the landing rule and the fan-out under every policy
+# ---------------------------------------------------------------------------
+
+
+@register_app("rz_raise")
+def _raise(inputs, outputs, app):
+    for o in outputs:
+        o.write("partial")          # dropped with the failed attempt
+    raise RuntimeError("intentional")
+
+
+@register_app("rz_fail_once")
+def _fail_once(inputs, outputs, app):
+    """Fails the first time it runs, then doubles its input.  The marker
+    is a file, so a worker process and a retry in it agree."""
+    marker = app.meta["marker"]
+    if not os.path.exists(marker):
+        open(marker, "w").close()
+        raise RuntimeError("transient")
+    _double(inputs, outputs, app)
+
+
+_SEEN: list = []                  # (uid, thread name) per rz_where run
+_BARRIER = {"b": None}            # a wave's apps meet here when set
+_FAILED: set = set()              # rz_flaky_once apps that failed once
+
+
+@register_app("rz_where")
+def _where(inputs, outputs, app):
+    _SEEN.append((app.uid, threading.current_thread().name))
+    if _BARRIER["b"] is not None:
+        _BARRIER["b"].wait()
+    for o in outputs:
+        o.write(app.uid)
+
+
+def landing_lg(marker):
+    """Three payload writers, one app that raises, one that fails once."""
+    g = GraphBuilder("rz_landing")
+    g.data("src")
+    for k in range(3):
+        g.component(f"w{k}", app="rz_double", time=1.0)
+        g.data(f"o{k}", volume=10)
+        g.chain("src", f"w{k}", f"o{k}")
+    g.component("bad", app="rz_raise", time=1.0)
+    g.data("bad_out")
+    g.chain("src", "bad", "bad_out")
+    g.component("flaky", app="rz_fail_once", time=1.0, marker=marker)
+    g.data("flaky_out")
+    g.chain("src", "flaky", "flaky_out")
+    return g.graph()
+
+
+@register_app("rz_flaky_once")
+def _flaky_once(inputs, outputs, app):
+    if app.uid not in _FAILED:
+        _FAILED.add(app.uid)
+        raise RuntimeError("transient")
+    for o in outputs:
+        o.write(app.uid)
+
+
+def wave_lg(n, app="rz_where"):
+    g = GraphBuilder("rz_wave")
+    g.data("src")
+    for k in range(n):
+        g.component(f"w{k}", app=app)
+        g.data(f"o{k}")
+        g.chain("src", f"w{k}", f"o{k}")
+    return g.graph()
+
+
+def _placed_wave(n, nodes, app="rz_where"):
+    """``wave_lg(n)`` with app ``w{k}`` and its output on node k % nodes."""
+    placement = {"src": "node0"}
+    for k in range(n):
+        placement[f"w{k}"] = placement[f"o{k}"] = f"node{k % nodes}"
+    master, s, pgt = _manual_compiled(wave_lg(n, app), placement,
+                                      num_nodes=nodes)
+    s.write("src", 1)
+    _SEEN.clear()
+    return master, s, pgt
+
+
+POLICIES = {
+    "none": None,
+    "retry": ResilienceConfig(retry=RetryPolicy(max_attempts=2)),
+    # speculation's per-app tasks and poll loop, with no duplicate fired:
+    # a duplicate's win would stamp another node
+    "stragglers": ResilienceConfig(stragglers=StragglerPolicy(
+        factor=1e3, min_runtime=60.0)),
+}
+
+
+class TestOneDispatcher:
+    @pytest.mark.parametrize("workers", ["thread", "process"])
+    @pytest.mark.parametrize("policy", list(POLICIES))
+    def test_one_landing_rule(self, policy, workers, tmp_path, monkeypatch):
+        stamps = []
+        real = Timeline.stamp
+
+        def counted(tl, i, t0, t1, wave, node=None):
+            stamps.append((i, t0, t1, node))
+            real(tl, i, t0, t1, wave, node)
+
+        monkeypatch.setattr(Timeline, "stamp", counted)
+        cfg = EngineConfig(execution="compiled", num_nodes=2,
+                           algorithm="none", workers=workers,
+                           resilience=POLICIES[policy],
+                           telemetry=TelemetryConfig(timeline=True))
+        with Pipeline(cfg) as p:
+            rep = p.run(landing_lg(str(tmp_path / "marker")), timeout=60,
+                        inputs={"src": 1})
+            s, pgt = p.session, p.session.pgt
+            retried = policy == "retry"
+            # one retry each for the app that raises and the flaky one
+            assert rep.retries == (2 if retried else 0)
+            done = {f"w{k}" for k in range(3)} | {f"o{k}" for k in range(3)}
+            done |= {"src"} | ({"flaky", "flaky_out"} if retried else set())
+            for i in range(pgt.num_drops):
+                u = pgt.uid_of(i)
+                want = DropState.COMPLETED if u in done else DropState.ERROR
+                assert s.state_of(u) is want, u
+            for k in range(3):
+                assert s.read(f"o{k}") == 2
+            # a failed attempt's writes never land, whatever the policy
+            assert not s.payload_present[s.index_of("bad_out")]
+            errs = {r.uid: r.error_info.strip().splitlines()[-1]
+                    for r in s.errors()}
+            assert errs["bad"] == "RuntimeError: intentional"
+            if retried:
+                assert s.read("flaky_out") == 2
+            else:
+                assert errs["flaky"] == "RuntimeError: transient"
+                assert not s.payload_present[s.index_of("flaky_out")]
+            # one stamp per registry app, on the node that executed it
+            apps = sorted(s.index_of(u)
+                          for u in ("w0", "w1", "w2", "bad", "flaky"))
+            assert sorted(i for i, *_ in stamps) == apps
+            for i, t0, t1, node in stamps:
+                assert t0 <= t1
+                assert node == pgt.node_ids[i]
+                assert s.timeline.node[i] == pgt.node_ids[i]
+
+    def test_retry_only_wave_overlaps_two_node_pools(self):
+        """A retry policy keeps the plain path's node overlap: the two
+        apps meet at a barrier, which a serial wave would break."""
+        master, s, _ = _placed_wave(2, nodes=2)
+        _BARRIER["b"] = threading.Barrier(2, timeout=5)
+        try:
+            ok, stats = execute_resilient(
+                s, master, ResilienceConfig(retry=RetryPolicy(2)),
+                timeout=30)
+        finally:
+            _BARRIER["b"] = None
+            master.shutdown()
+        assert ok and stats.retries == 0
+        assert set(s.status()) == {"COMPLETED"}
+        threads = dict(_SEEN)
+        assert threads["w0"].startswith("ndm-node0_")
+        assert threads["w1"].startswith("ndm-node1_")
+
+    def test_concurrent_landings_lose_no_count(self):
+        """Eight node pools land and retry at once, with the interpreter
+        switching threads as often as it can: every retry is counted and
+        every payload lands."""
+        n = 64
+        master, s, _ = _placed_wave(n, nodes=8, app="rz_flaky_once")
+        _FAILED.clear()
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            ok, stats = execute_resilient(
+                s, master, ResilienceConfig(retry=RetryPolicy(2)),
+                timeout=60)
+        finally:
+            sys.setswitchinterval(old)
+            master.shutdown()
+        assert ok
+        assert stats.retries == s.retries == n
+        assert set(s.status()) == {"COMPLETED"}
+        for k in range(n):
+            assert s.read(f"o{k}") == f"w{k}"
+
+    @pytest.mark.parametrize("nodes", [1, 2])
+    def test_serving_fan_out(self, nodes):
+        """The serving benchmark's case: a wave whose registry apps all
+        sit on one thread-backed node runs inline in the thread that
+        called ``execute_frontier``, in node-sorted order.  A wave over
+        two nodes runs on their two pools."""
+        master, s, pgt = _placed_wave(4, nodes)
+        try:
+            assert execute_frontier(s, timeout=30,
+                                    executors=master.node_executors())
+        finally:
+            master.shutdown()
+        assert set(s.status()) == {"COMPLETED"}
+        uids = [f"w{k}" for k in range(4)]
+        threads = dict(_SEEN)
+        if nodes == 1:
+            assert [u for u, _ in _SEEN] == sorted(uids, key=pgt.index_of)
+            assert set(threads.values()) == {threading.current_thread().name}
+        else:
+            for k, u in enumerate(uids):
+                assert threads[u].startswith(f"ndm-node{k % 2}_"), threads
 
 
 # ---------------------------------------------------------------------------
