@@ -1,15 +1,18 @@
 """jit'd dispatch wrappers: model-layout in, kernel-layout inside.
 
 ``flash_attention`` / ``ssd_scan`` are what the model layers call when
-``use_kernel=True``.  On the TPU the ``pallas_call`` lowers to Mosaic; on
-the CPU backend (the test suite) the same body runs in Pallas interpret mode;
-any other backend is refused rather than quietly interpreted.  The jnp
+``use_kernel=True``; ``ssd_decode`` is what mamba2's decode step always
+calls (the ``ssm`` family).  On the TPU the ``pallas_call`` lowers to
+Mosaic; on the CPU backend (the test suite) the same body runs in Pallas
+interpret mode; any other backend is refused rather than quietly
+interpreted.  The jnp
 reference path (`repro.kernels.ref`) is the oracle and the default dry-run
 path (the dry-run measures the XLA program, and Mosaic kernels are opaque
 to HLO cost analysis anyway).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -17,6 +20,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .flash_attention import flash_attention_bhsd
+from .ssd_decode import ssd_decode_bhpn
 from .ssd_scan import ssd_scan_bhsd
 
 
@@ -71,6 +75,23 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     y = jnp.transpose(y, (0, 2, 1, 3))               # (B,S,H,P)
     # model layout state: (B,H,N,P)
     return y, state
+
+
+def ssd_decode(states: jax.Array, out: jax.Array, layer: jax.Array,
+               decay: jax.Array, x: jax.Array, bdt: jax.Array, c: jax.Array
+               ) -> Tuple[jax.Array, jax.Array]:
+    """One layer's decode SSM state update and read-out
+    (``ssd_decode_bhpn``): ``out`` with layer ``layer`` set to the new
+    state, and y (B,H,P).
+
+    Compiled or interpreted by the platform the step is lowered for, not by
+    the default backend, so a step compiled for a TPU from a CPU-only host
+    carries the Mosaic kernel; any platform but the two is refused.
+    """
+    return jax.lax.platform_dependent(
+        states, out, layer, decay, x, bdt, c,
+        tpu=functools.partial(ssd_decode_bhpn, interpret=False),
+        cpu=functools.partial(ssd_decode_bhpn, interpret=True))
 
 
 # convenience: oracle access under one namespace

@@ -63,3 +63,21 @@ def ssd_reference(x: jax.Array, dt: jax.Array, a: jax.Array,
     h, ys = jax.lax.scan(step, h0.astype(jnp.float32), jnp.arange(S))
     y = jnp.moveaxis(ys, 0, 2)                          # (B,H,S,P)
     return y.astype(x.dtype), h.astype(x.dtype)
+
+
+def ssd_decode_reference(states: jax.Array, out: jax.Array, layer: jax.Array,
+                         decay: jax.Array, x: jax.Array, bdt: jax.Array,
+                         c: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One layer's decode state update and read-out, in the XLA form of
+    ``models/ssm.py:mamba2_decode_step``.
+
+    states: (L,B,H,P,N); out: (L,B,H,P,N) float32; decay: (B,H);
+    x: (B,H,P); bdt: (B,H,N); c: (B,H,N).
+    s = states[layer] * decay + (x (x) bdt) rounded to the state's dtype;
+    y = sum_N s * C.  Returns (out with layer ``layer`` set to s, y).
+    """
+    state = states[layer]
+    s = (state * decay[..., None, None]
+         + jnp.einsum("bhp,bhk->bhpk", x, bdt).astype(state.dtype))
+    y = jnp.einsum("bhpk,bhk->bhp", s.astype(jnp.float32), c)
+    return out.at[layer].set(s.astype(out.dtype)), y

@@ -27,8 +27,8 @@ from .attention import (attention, decode_attention, decode_cross_attention,
 from .common import (ArchConfig, KeyGen, activation_fn, cross_entropy,
                      dense_init, rms_norm, sinusoidal_positions, softcap)
 from .moe import init_moe, moe_block
-from .ssm import (init_mamba2, init_ssm_cache, mamba2_decode_step,
-                  mamba2_forward)
+from .ssm import (init_mamba2, init_ssm_cache, mamba2_decode_layer,
+                  mamba2_decode_step, mamba2_forward)
 from ..sharding import ctx as sctx
 
 
@@ -469,6 +469,16 @@ def _in_place_experts(cfg: ArchConfig, layers: Dict[str, Any]
     return {**layers, "moe": moe}, stacks
 
 
+def _mamba_decode_body(cfg: ArchConfig):
+    """The scan body of a Mamba-2 decode in the XLA form: one layer's
+    (params, cache) in, its cache out."""
+    def body(h, layer):
+        p, c = layer
+        y, c2 = mamba2_decode_step(p["mamba"], rms_norm(h, p["norm"]), c, cfg)
+        return h + y, c2
+    return body
+
+
 def decode_step(params: Dict[str, Any], cfg: ArchConfig,
                 cache: Dict[str, Any], tokens: jax.Array,
                 pos: jax.Array) -> Tuple[jax.Array, Dict[str, Any]]:
@@ -507,13 +517,31 @@ def decode_step(params: Dict[str, Any], cfg: ArchConfig,
                 "kv": kv, "moe": _count_moe(cache["moe"], rows)}
         else:
             new_cache = {"kv": ys}
+    elif cfg.family == "ssm" and jax.sharding.get_abstract_mesh().empty:
+        # The state stack is closed over and read in place by each layer's
+        # kernel, which writes the new state into a float32 stack carried
+        # through the scan: a state scanned in and out would be read twice
+        # (update, then read-out) and written once more into the scan's
+        # output.
+        states = cache["ssm"]["state"]
+
+        def body(carry, layer):
+            h, out = carry
+            p, conv, i = layer
+            y, conv2, out = mamba2_decode_layer(
+                p["mamba"], rms_norm(h, p["norm"]), conv, states, out, i,
+                cfg)
+            return (h + y, out), conv2
+        (h, state), conv = _scan(
+            body, (x, jax.lax.empty(states.shape, jnp.float32)),
+            (params["layers"], cache["ssm"]["conv"],
+             jnp.arange(cfg.num_layers)))
+        new_cache = {"ssm": {"conv": conv, "state": state}}
     elif cfg.family == "ssm":
-        def body(h, layer):
-            p, c = layer
-            y, c2 = mamba2_decode_step(
-                p["mamba"], rms_norm(h, p["norm"]), c, cfg)
-            return h + y, c2
-        h, ssm = _scan(body, x, (params["layers"], cache["ssm"]))
+        # Sharded over a mesh (the dry-run's programs): XLA's partitioner
+        # does not split a Pallas kernel, so the step keeps the XLA form.
+        h, ssm = _scan(_mamba_decode_body(cfg), x,
+                       (params["layers"], cache["ssm"]))
         new_cache = {"ssm": ssm}
     elif cfg.family == "hybrid":
         per = cfg.shared_attn_period
@@ -523,15 +551,9 @@ def decode_step(params: Dict[str, Any], cfg: ArchConfig,
         grouped_c = jax.tree.map(
             lambda a: a.reshape(groups, per, *a.shape[1:]), cache["ssm"])
 
-        def inner(h, layer):
-            p, c = layer
-            y, c2 = mamba2_decode_step(
-                p["mamba"], rms_norm(h, p["norm"]), c, cfg)
-            return h + y, c2
-
         def outer(h, layer):
             gp, gc, kv = layer
-            h, gc2 = _scan(inner, h, (gp, gc))
+            h, gc2 = _scan(_mamba_decode_body(cfg), h, (gp, gc))
             sp = params["shared"]
             a, kv2 = decode_attention(
                 sp["attn"], rms_norm(h, sp["attn_norm"]), kv, pos, cfg)

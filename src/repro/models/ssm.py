@@ -174,20 +174,21 @@ def init_ssm_cache(cfg: ArchConfig, batch: int, dtype: Any
     }
 
 
-def mamba2_decode_step(p: Dict[str, jax.Array], x: jax.Array,
-                       cache: Dict[str, jax.Array], cfg: ArchConfig
-                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """x: (B,1,d) one token; cache: conv window + SSM state."""
+def _decode_inputs(p: Dict[str, jax.Array], x: jax.Array, conv: jax.Array,
+                   cfg: ArchConfig):
+    """The decode step up to the state update.  x: (B,1,d) one token;
+    conv: (B,W-1,C) window.  Returns the gate z, the heads' input xh
+    (B,h,P), the decay (B,h) and B*dt (B,h,n) in float32, C (B,h,n) and
+    the new conv window."""
     B = x.shape[0]
     di, n, g = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_groups
     h, P = cfg.ssm_heads, cfg.ssm_headdim
     zxbcdt = jnp.einsum("bsd,dk->bsk", x, p["in_proj"])
     z, xin, b_, c_, dt = _split_proj(cfg, zxbcdt)
     conv_in = jnp.concatenate([xin, b_, c_], axis=-1)[:, 0]   # (B,C)
-    window = jnp.concatenate([cache["conv"], conv_in[:, None]], axis=1)
+    window = jnp.concatenate([conv, conv_in[:, None]], axis=1)
     conv_out = jax.nn.silu(
         jnp.einsum("bwc,wc->bc", window, p["conv_w"]) + p["conv_b"])
-    new_conv = window[:, 1:]
     xin, b_, c_ = jnp.split(conv_out, [di, di + g * n], axis=-1)
 
     dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32)
@@ -198,12 +199,44 @@ def mamba2_decode_step(p: Dict[str, jax.Array], x: jax.Array,
     bh = jnp.repeat(b_.reshape(B, g, n), rep, axis=1)          # (B,h,n)
     ch = jnp.repeat(c_.reshape(B, g, n), rep, axis=1)
     xh = xin.reshape(B, h, P)
+    return z, xh, decay, bh * dt[..., None], ch, window[:, 1:]
+
+
+def _decode_output(p: Dict[str, jax.Array], x: jax.Array, z: jax.Array,
+                   xh: jax.Array, y: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """The decode step after the read-out y (B,h,P) float32: the D skip,
+    the gate, the norm and the output projection."""
+    y = y + xh.astype(jnp.float32) * p["D"][None, :, None]
+    y = y.reshape(x.shape[0], 1, cfg.ssm_inner).astype(x.dtype)
+    y = rms_norm(y * jax.nn.silu(z), p["norm"])
+    return jnp.einsum("bsk,kd->bsd", y, p["out_proj"])
+
+
+def mamba2_decode_step(p: Dict[str, jax.Array], x: jax.Array,
+                       cache: Dict[str, jax.Array], cfg: ArchConfig
+                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """x: (B,1,d) one token; cache: conv window + SSM state."""
+    z, xh, decay, bdt, ch, new_conv = _decode_inputs(p, x, cache["conv"],
+                                                      cfg)
     state = (cache["state"] * decay[..., None, None]
              + jnp.einsum("bhp,bhk->bhpk", xh,
-                          bh * dt[..., None]).astype(cache["state"].dtype))
+                          bdt).astype(cache["state"].dtype))
     y = jnp.einsum("bhpk,bhk->bhp", state.astype(jnp.float32), ch)
-    y = y + xh.astype(jnp.float32) * p["D"][None, :, None]
-    y = y.reshape(B, 1, di).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z), p["norm"])
-    out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"])
+    out = _decode_output(p, x, z, xh, y, cfg)
     return out, {"conv": new_conv, "state": state}
+
+
+def mamba2_decode_layer(p: Dict[str, jax.Array], x: jax.Array,
+                        conv: jax.Array, states: jax.Array, out: jax.Array,
+                        layer: jax.Array, cfg: ArchConfig
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``mamba2_decode_step`` for layer ``layer`` of a whole cache, with
+    the state update and read-out in one kernel (``kernels/ssd_decode.py``).
+
+    states: the cache's (L,B,h,P,n) state stack, read at ``layer`` in
+    place; out: the (L,B,h,P,n) float32 stack of new states, returned
+    with layer ``layer`` written.  Returns (y, new conv window, out)."""
+    from ..kernels import ops as kops
+    z, xh, decay, bdt, ch, new_conv = _decode_inputs(p, x, conv, cfg)
+    out, y = kops.ssd_decode(states, out, layer, decay, xh, bdt, ch)
+    return _decode_output(p, x, z, xh, y, cfg), new_conv, out

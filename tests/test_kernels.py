@@ -7,7 +7,9 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
+from repro.kernels import ssd_decode
 from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.kernels.ssd_decode import ssd_decode_bhpn
 from repro.kernels.ssd_scan import ssd_scan_bhsd
 
 
@@ -163,6 +165,76 @@ class TestSSDScan:
                                   cc.astype(jnp.float32))
         np.testing.assert_allclose(np.asarray(y, np.float32),
                                    np.asarray(yr), atol=5e-2, rtol=5e-2)
+
+
+class TestSSDDecode:
+    """The decode state kernel against the XLA form it replaces, with the
+    state bf16 (the first step after prefill) and float32 (every later
+    step)."""
+
+    @staticmethod
+    def inputs(layers, b, h, p, n, state_dtype):
+        states = rnd(30, (layers, b, h, p, n), state_dtype)
+        decay = jnp.exp(-jax.nn.softplus(rnd(31, (b, h), jnp.float32)))
+        x = rnd(32, (b, h, p), jnp.bfloat16)
+        bdt = rnd(33, (b, h, n), jnp.float32, 0.5)
+        c = rnd(34, (b, h, n), jnp.bfloat16)
+        return states, decay, x, bdt, c
+
+    @pytest.mark.parametrize("state_dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("layers,b,h,p,n,block_heads", [
+        (2, 2, 8, 16, 16, 8),
+        (3, 1, 4, 8, 32, 4),
+        (3, 2, 16, 16, 16, 8),       # two blocks of heads
+        (2, 3, 24, 8, 16, 8),        # three blocks of heads
+    ])
+    def test_matches_the_xla_form(self, monkeypatch, layers, b, h, p, n,
+                                  block_heads, state_dtype):
+        monkeypatch.setattr(ssd_decode, "BLOCK_BYTES",
+                            block_heads * p * n * 4)
+        assert ssd_decode._heads_per_block(h, p, n) == block_heads
+        states, decay, x, bdt, c = self.inputs(layers, b, h, p, n,
+                                               state_dtype)
+        out = jnp.zeros((layers, b, h, p, n), jnp.float32)
+        layer = jnp.int32(layers - 1)
+        got, y = jax.jit(lambda *a: ssd_decode_bhpn(*a, interpret=True))(
+            states, out, layer, decay, x, bdt, c)
+        want, y_want = ref.ssd_decode_reference(states, out, layer, decay,
+                                                x, bdt, c)
+        assert got.dtype == y.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-6, rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_want),
+                                   atol=1e-5, rtol=1e-5)
+
+    def test_first_step_rounds_the_update_to_the_input_state(self):
+        """With a bf16 input state the outer-product term is rounded to
+        bf16 before the float32 add, as the XLA form does; unrounded, the
+        new state would differ."""
+        states, decay, x, bdt, c = self.inputs(1, 2, 8, 16, 16,
+                                               jnp.bfloat16)
+        out = jnp.zeros(states.shape, jnp.float32)
+        got, _ = ssd_decode_bhpn(states, out, jnp.int32(0), decay, x, bdt,
+                                 c, interpret=True)
+        want, _ = ref.ssd_decode_reference(states, out, 0, decay, x, bdt, c)
+        unrounded = (states[0].astype(jnp.float32) * decay[..., None, None]
+                     + jnp.einsum("bhp,bhk->bhpk", x, bdt))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-6, rtol=1e-6)
+        assert float(jnp.abs(got[0] - unrounded).max()) > 1e-4
+
+    def test_writes_only_its_layer(self):
+        """Layer ``layer`` of the output stack is written; its other layers
+        and the input stack are left as they were."""
+        states, decay, x, bdt, c = self.inputs(4, 2, 8, 16, 16, jnp.float32)
+        out = jnp.full(states.shape, 7.0, jnp.float32)
+        before = np.asarray(states)
+        got, _ = jax.jit(lambda *a: ssd_decode_bhpn(*a, interpret=True))(
+            states, out, jnp.int32(2), decay, x, bdt, c)
+        got = np.asarray(got)
+        assert not np.any(got[2] == 7.0)
+        assert np.all(np.delete(got, 2, axis=0) == 7.0)
+        np.testing.assert_array_equal(np.asarray(states), before)
 
 
 class TestModelScanAgreement:

@@ -127,6 +127,45 @@ class TestSmoke:
         assert diff < 2e-2, (arch, diff)
 
 
+@pytest.mark.parametrize("arch", ["mamba2_1_3b", "mamba2_1_3b-wide_state",
+                                  "zamba2_2_7b"])
+def test_decode_state_kernel_keeps_the_xla_numbers(arch, monkeypatch):
+    """Decode steps of a tiny ``ssm`` config, whose state update and
+    read-out run in one kernel (``kernels/ssd_decode.py``), give the
+    logits and cache of the XLA form within float32 rounding; the first
+    step reads a bf16 state, as after prefill, the later ones float32.
+    The ``hybrid`` family keeps the XLA form: its step has no kernel."""
+    from repro.kernels import ops, ref
+    cfg = smoke_config(arch)
+    params = M.init_params(cfg, KEY)
+    steps = 3
+    cache = M.init_cache(cfg, B, steps)
+    cache["ssm"]["state"] = jax.random.normal(
+        KEY, cache["ssm"]["state"].shape).astype(jnp.bfloat16)
+    toks = jax.random.randint(KEY, (B, steps), 0, cfg.vocab_size)
+
+    def decode():
+        step = jax.jit(lambda c, t, pos: M.decode_step(params, cfg, c, t,
+                                                       pos))
+        c, logits = cache, []
+        for i in range(steps):
+            out, c = step(c, toks[:, i:i + 1], jnp.int32(i))
+            logits.append(out)
+        jaxpr = str(jax.make_jaxpr(step)(cache, toks[:, :1], jnp.int32(0)))
+        return jnp.stack(logits), c, "pallas_call" in jaxpr
+
+    logits, after, kernel = decode()
+    monkeypatch.setattr(ops, "ssd_decode", ref.ssd_decode_reference)
+    want_logits, want, _ = decode()
+    assert kernel == (cfg.family == "ssm")
+    assert after["ssm"]["state"].dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits),
+                               atol=1e-5, rtol=1e-5)
+    for got, ok in zip(jax.tree.leaves(after), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ok),
+                                   atol=1e-5, rtol=1e-5)
+
+
 class TestFullConfigs:
     @pytest.mark.parametrize("arch", ARCH_NAMES)
     def test_exact_assigned_numbers(self, arch):

@@ -84,28 +84,45 @@ def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_mamba2_decode_step_keeps_the_state_layout(one_chip):
+MAMBA2_MB = 16
+
+
+def _mamba2_decode(one_chip, state_dtype):
+    """mamba2-1.3b's served decode step (mb 16), compiled with its cache's
+    state in ``state_dtype``: bf16 out of prefill, float32 after."""
+    cfg = get_config("mamba2_1_3b")
+
+    def spec(path, a):
+        state = "state" in jax.tree_util.keystr(path)
+        return _spec(a.shape, state_dtype if state else a.dtype, one_chip)
+    params = jax.eval_shape(lambda k: M.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map_with_path(spec, params)
+    cache = jax.tree_util.tree_map_with_path(
+        spec, jax.eval_shape(lambda: M.init_cache(cfg, MAMBA2_MB, 1)))
+    tokens = _spec((MAMBA2_MB, 1), jnp.int32, one_chip)
+    pos = _spec((), jnp.int32, one_chip)
+    return jax.jit(make_decode_step(cfg)).lower(
+        params, cache, tokens, pos).compile()
+
+
+@pytest.fixture(scope="module")
+def mamba2_decode(one_chip):
+    """The decode step of every step after the first (float32 state)."""
+    return _mamba2_decode(one_chip, jnp.float32)
+
+
+def test_mamba2_decode_step_keeps_the_state_layout(mamba2_decode):
     """The decode step at the served microbatch, with the float32 state of
     every step after the first, updates each layer's state in the layout
     the cache stores: no copy re-lays out a layer's state, and no
     temporary holds one (the state stays in the output stack)."""
     cfg = get_config("mamba2_1_3b")
-    mb = 16
+    mb = MAMBA2_MB
     h, p, n = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
-
-    def spec(path, a):
-        state = "state" in jax.tree_util.keystr(path)
-        return _spec(a.shape, jnp.float32 if state else a.dtype, one_chip)
-    params = jax.eval_shape(lambda k: M.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    params = jax.tree_util.tree_map_with_path(spec, params)
-    cache = jax.tree_util.tree_map_with_path(
-        spec, jax.eval_shape(lambda: M.init_cache(cfg, mb, 1)))
-    assert cache["ssm"]["state"].shape == (cfg.num_layers, mb, h, p, n)
-    tokens = _spec((mb, 1), jnp.int32, one_chip)
-    pos = _spec((), jnp.int32, one_chip)
-    compiled = jax.jit(make_decode_step(cfg)).lower(
-        params, cache, tokens, pos).compile()
+    assert jax.eval_shape(lambda: M.init_cache(cfg, mb, 1))[
+        "ssm"]["state"].shape == (cfg.num_layers, mb, h, p, n)
+    compiled = mamba2_decode
 
     layer_state = re.compile(
         rf"= f32\[(1,)?{mb},{h},({p},{n}|{n},{p})\]\S* copy\(")
@@ -116,38 +133,87 @@ def test_mamba2_decode_step_keeps_the_state_layout(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer
 
 
+def _computations(text):
+    """The compiled module's computations: name -> (header, body lines)."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%\S+) \(", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = (line, [])
+        elif name and line.startswith("  "):
+            comps[name][1].append(line)
+    return comps
+
+
+@pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])
+def test_mamba2_decode_updates_the_state_in_one_kernel(
+        state_dtype, mamba2_decode, one_chip):
+    """Both decode programs (bf16 state in on the first step, float32 on
+    every later one) update each layer's state in one Pallas kernel inside
+    the layer loop: the kernel reads the layer's state in place from the
+    input stack and writes it into a float32 output stack that starts as
+    an unfilled buffer (``AllocateBuffer``).  No fusion reads the state
+    for the read-out's reduce, no copy holds a stack or a layer of state,
+    and the temporaries stay under one layer's state."""
+    cfg = get_config("mamba2_1_3b")
+    L, mb = cfg.num_layers, MAMBA2_MB
+    h, p, n = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
+    compiled = (mamba2_decode if state_dtype == "float32" else
+                _mamba2_decode(one_chip, jnp.dtype(state_dtype)))
+    text = compiled.as_text()
+    comps = _computations(text)
+
+    loops = re.findall(r" while\(.*?body=(%[^,\s]+)", text)
+    assert len(loops) == 1, loops
+    body = "\n".join(comps[loops[0]][1])
+    assert body.count('custom_call_target="tpu_custom_call"') == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+    state = rf"(f32|bf16)\[({L},|1,)?{mb},{h},({p},{n}|{n},{p})\]"
+    readers = [name for name, (header, lines) in comps.items()
+               if re.search(state, header)
+               and any(" reduce(" in line for line in lines)]
+    assert not readers, readers
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= {state}\S* copy\(", line)]
+    assert not copies, copies
+
+    entry = next(lines for header, lines in comps.values()
+                 if header.startswith("ENTRY"))
+    stack = re.escape(f"f32[{L},{mb},{h},{p},{n}]")
+    made = [line.strip()[:160] for line in entry if re.search(
+        rf"= {stack}\S* (?!parameter|get-tuple-element)[a-z-]+\(", line)]
+    assert len(made) == 1 and 'custom_call_target="AllocateBuffer"' in \
+        made[0], made
+    one_layer = mb * h * p * n * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer
+
+
 def _ops(compiled):
     """The number of HLO instructions of a compiled program."""
     return sum(1 for line in compiled.as_text().splitlines()
                if re.match(r"\s*(ROOT )?%\S+ = ", line))
 
 
-def test_mamba2_steps_keep_their_programs(one_chip):
-    """Granite's multipliers are branched on in Python at their neutral
-    values, so mamba2-1.3b's decode and prefill programs (the benchmarked
-    cell's, at mb 16 and 512-token prompts, the shared ``embed_tokens``
-    and ``logits_fn`` included) compile to what they did before the
-    multipliers existed: the same temporaries and instruction count."""
+def test_mamba2_steps_keep_their_programs(mamba2_decode, one_chip):
+    """mamba2-1.3b's decode and prefill programs (the benchmarked cell's,
+    at mb 16 and 512-token prompts, the shared ``embed_tokens`` and
+    ``logits_fn`` included) keep their temporaries and instruction count:
+    prefill's since before granite's multipliers existed (branched on in
+    Python at their neutral values), decode's since its state update and
+    read-out became one kernel."""
     cfg = get_config("mamba2_1_3b")
-    mb, prompt = 16, 512
-
-    def spec(path, a):
-        state = "state" in jax.tree_util.keystr(path)
-        return _spec(a.shape, jnp.float32 if state else a.dtype, one_chip)
     params = jax.tree.map(
         lambda a: _spec(a.shape, a.dtype, one_chip),
         jax.eval_shape(lambda k: M.init_params(cfg, k),
                        jax.random.PRNGKey(0)))
-    cache = jax.tree_util.tree_map_with_path(
-        spec, jax.eval_shape(lambda: M.init_cache(cfg, mb, 1)))
-    decode = jax.jit(make_decode_step(cfg)).lower(
-        params, cache, _spec((mb, 1), jnp.int32, one_chip),
-        _spec((), jnp.int32, one_chip)).compile()
     prefill = jax.jit(make_prefill_step(cfg)).lower(
-        params, {"tokens": _spec((mb, prompt), jnp.int32, one_chip)}
+        params, {"tokens": _spec((MAMBA2_MB, 512), jnp.int32, one_chip)}
     ).compile()
+    decode = mamba2_decode
     assert (decode.memory_analysis().temp_size_in_bytes, _ops(decode)) == (
-        225_792, 485)
+        193_536, 474)
     assert (prefill.memory_analysis().temp_size_in_bytes, _ops(prefill)) == (
         582_951_936, 759)
 
@@ -227,3 +293,16 @@ def test_granite_grouped_matmuls_read_the_experts_in_place(step, request):
     if step == "granite_decode":
         one_layer = 3 * e * d * f * 2
         assert compiled.memory_analysis().temp_size_in_bytes < one_layer
+
+
+@pytest.mark.parametrize("step,program", [
+    ("granite_decode", (15_746_560, 901)),
+    ("granite_prefill", (206_484_480, 826)),
+])
+def test_granite_steps_keep_their_programs(step, program, request):
+    """Granite's programs contain no SSM op, so the mamba2 decode kernel
+    leaves them as they were: the same temporaries and instruction count
+    for the served decode and prefill steps."""
+    compiled = request.getfixturevalue(step)
+    assert (compiled.memory_analysis().temp_size_in_bytes,
+            _ops(compiled)) == program
